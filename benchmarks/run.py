@@ -71,6 +71,8 @@ def main(argv):
         print(f"unknown flags: {unknown}; known: --smoke", file=sys.stderr)
         return 2
     names = [a for a in argv if not a.startswith("--")] or list(BENCHES)
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     summary = []
     for name in names:
         t0 = time.time()

@@ -2,12 +2,20 @@
 scalar-prefetched page tables.
 
 The page table IS the paper's programmable LD stage: instead of a
-fixed-function contiguous DMA, each (batch, kv-head, page-slot) grid
-step computes its own source address from the prefetched table
-(``tbl[b, i]``) and stages exactly one resident page into VMEM.  CAL is
-the usual online-softmax pair of MACs; FLOW carries (m, l, acc) across
-the page sweep in VMEM scratch; ST writes the normalized output once —
-output-stationary, like All-Reuse.
+fixed-function contiguous DMA, each (batch, page-slot) grid step
+computes its own source address from the prefetched table
+(``tbl[b, i]``) and stages exactly one resident page, all its KV heads,
+into VMEM.  CAL is the usual online-softmax pair of MACs, run for every
+query head at once against the page's token-major (ps * KVH, Dh) rows,
+with each query head masked to the rows of its own kv head; FLOW
+carries (m, l, acc) across the page sweep in VMEM scratch; ST writes
+the normalized output once — output-stationary, like All-Reuse.
+
+A page block spans the array's full (KVH, Dh) trailing pair because the
+TPU lowering tiles the last two dims of every block by (8, 128) unless
+they equal the array's own: a one-kv-head block is refused on the chip.
+The masked GQA columns cost KVH x the score MACs, which decode (bound by
+the page reads) does not notice.
 
 Pages beyond a sequence's length are skipped entirely (``pl.when``),
 the paged analogue of Sparse PC Inc: work that is not addressed is
@@ -48,9 +56,10 @@ NEG_INF = -1e30
 
 
 def _kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-            m_ref, l_ref, acc_ref, *, ps: int, n_slots: int, scale: float):
+            m_ref, l_ref, acc_ref, *, ps: int, kvh: int, n_slots: int,
+            scale: float):
     b = pl.program_id(0)
-    i = pl.program_id(2)
+    i = pl.program_id(1)
 
     @pl.when(i == 0)
     def _init():
@@ -62,65 +71,71 @@ def _kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(i * ps < length)
     def _page():
-        q = q_ref[0, 0].astype(jnp.float32) * scale          # (G, Dh)
-        k = k_ref[0, :, 0].astype(jnp.float32)               # (ps, Dh)
-        v = v_ref[0, :, 0].astype(jnp.float32)
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)
-        k_pos = i * ps + jax.lax.broadcasted_iota(
-            jnp.int32, (1, ps), 1)[0]
-        s = jnp.where((k_pos < length)[None, :], s, NEG_INF)
+        q = q_ref[0].astype(jnp.float32) * scale             # (H, Dh)
+        H, Dh = q.shape
+        # the page's (ps, KVH, Dh) block flattened token-major: row j
+        # is token j // kvh of kv head j % kvh
+        k = k_ref[0].reshape(ps * kvh, Dh).astype(jnp.float32)
+        v = v_ref[0].reshape(ps * kvh, Dh).astype(jnp.float32)
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        # query head h reads kv head h // G only, and tokens < length
+        own = (col % kvh) == row // (H // kvh)
+        s = jnp.where(own & (i * ps + col // kvh < length), s, NEG_INF)
 
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1))
-        p = jnp.exp(s - m_new[:, None])
+        m_prev = m_ref[...]                                  # (H, 1)
+        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
         alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * alpha + p.sum(axis=-1)
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + jnp.dot(
+        l_ref[...] = l_ref[...] * alpha + p.sum(axis=-1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
             p, v, preferred_element_type=jnp.float32)
         m_ref[...] = m_new
 
     @pl.when(i == n_slots - 1)
     def _flush():
-        o_ref[0, 0] = (acc_ref[...]
-                       / jnp.maximum(l_ref[...], 1e-30)[:, None]
-                       ).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+                    ).astype(o_ref.dtype)
 
 
 def paged_attention_kernel(q: jax.Array, k_pages: jax.Array,
                            v_pages: jax.Array, page_tables: jax.Array,
                            lengths: jax.Array, *,
                            interpret: bool = False) -> jax.Array:
-    """q: (B, KVH, G, Dh); k/v_pages: (P, ps, KVH, Dh);
+    """q: (B, H, Dh); k/v_pages: (P, ps, KVH, Dh);
     page_tables: (B, n_slots) int32; lengths: (B,) int32.
-    Returns (B, KVH, G, Dh)."""
-    B, KVH, G, Dh = q.shape
-    _, ps, _, _ = k_pages.shape
+    Returns (B, H, Dh)."""
+    B, H, Dh = q.shape
+    _, ps, KVH, _ = k_pages.shape
     n_slots = page_tables.shape[1]
     scale = 1.0 / math.sqrt(Dh)
-
+    # every block spans its array's full trailing dims: (H, Dh) for q
+    # and the output, (KVH, Dh) for a page, as the TPU tiling requires
+    page_spec = pl.BlockSpec((1, ps, KVH, Dh),
+                             lambda b, i, tbl, ln: (tbl[b, i], 0, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, KVH, n_slots),
+        grid=(B, n_slots),
         in_specs=[
-            pl.BlockSpec((1, 1, G, Dh),
-                         lambda b, h, i, tbl, ln: (b, h, 0, 0)),
-            pl.BlockSpec((1, ps, 1, Dh),
-                         lambda b, h, i, tbl, ln: (tbl[b, i], 0, h, 0)),
-            pl.BlockSpec((1, ps, 1, Dh),
-                         lambda b, h, i, tbl, ln: (tbl[b, i], 0, h, 0)),
+            pl.BlockSpec((1, H, Dh), lambda b, i, tbl, ln: (b, 0, 0)),
+            page_spec,
+            page_spec,
         ],
-        out_specs=pl.BlockSpec((1, 1, G, Dh),
-                               lambda b, h, i, tbl, ln: (b, h, 0, 0)),
+        out_specs=pl.BlockSpec((1, H, Dh),
+                               lambda b, i, tbl, ln: (b, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((G,), jnp.float32),        # running max
-            pltpu.VMEM((G,), jnp.float32),        # running denom
-            pltpu.VMEM((G, Dh), jnp.float32),     # accumulator
+            pltpu.VMEM((H, 1), jnp.float32),      # running max
+            pltpu.VMEM((H, 1), jnp.float32),      # running denom
+            pltpu.VMEM((H, Dh), jnp.float32),     # accumulator
         ],
     )
     return pl.pallas_call(
-        functools.partial(_kernel, ps=ps, n_slots=n_slots, scale=scale),
+        functools.partial(_kernel, ps=ps, kvh=KVH, n_slots=n_slots,
+                          scale=scale),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, KVH, G, Dh), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, H, Dh), q.dtype),
         interpret=interpret,
         name="paged_attention",
     )(page_tables, lengths, q, k_pages, v_pages)

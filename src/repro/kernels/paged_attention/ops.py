@@ -1,4 +1,4 @@
-"""jit'd wrapper: (B, H, Dh) decode layout -> kernel layout and back."""
+"""jit'd entry point of the paged decode attention kernel."""
 from __future__ import annotations
 
 import functools
@@ -18,13 +18,8 @@ def paged_attention(q, k_pages, v_pages, page_tables, lengths, *,
 
     q: (B, H, Dh); k/v_pages: (P, ps, KVH, Dh); page_tables: (B, n)
     int32 page ids; lengths: (B,) attendable tokens.  Returns
-    (B, H, Dh).  GQA kept factored: q heads are grouped by kv head so
-    each page is staged once per kv head and reused across the group.
+    (B, H, Dh).  Each page is staged once, all its kv heads together,
+    and reused by every query head of the GQA group.
     """
-    B, H, Dh = q.shape
-    KVH = k_pages.shape[2]
-    G = H // KVH
-    qf = q.reshape(B, KVH, G, Dh)
-    out = paged_attention_kernel(qf, k_pages, v_pages, page_tables,
-                                 lengths, interpret=interpret)
-    return out.reshape(B, H, Dh)
+    return paged_attention_kernel(q, k_pages, v_pages, page_tables,
+                                  lengths, interpret=interpret)

@@ -148,7 +148,7 @@ class HloCost:
 def _split_operands(argstr: str) -> List[str]:
     """Operand names from an op's argument list (ignores literals).
 
-    The pinned jax 0.4.37 emits *typed* operand lists —
+    XLA's HLO text carries *typed* operand lists —
     ``dot(f32[256,256]{1,0} %Arg_0.1, ...)`` — where naive
     comma-splitting yields dtype tokens (``f32``) instead of names, so
     every symtab lookup missed and dot contractions collapsed to 1

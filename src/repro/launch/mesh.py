@@ -16,13 +16,10 @@ __all__ = ["make_mesh", "make_production_mesh", "make_local_mesh", "HW",
 
 
 def make_mesh(shape, axes):
-    """jax.make_mesh across jax versions: ``axis_types`` was added after
-    0.4.37 (where all axes are Auto by default)."""
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with every axis Auto: the sharding rules place
+    arrays and the compiler propagates, so no axis is Explicit."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

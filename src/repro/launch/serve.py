@@ -30,6 +30,7 @@ import numpy as np
 
 from repro import configs
 from repro.data.pipeline import SyntheticPipeline
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import build_model
 from repro.serve import Request, ServeOptions
 from repro.serve.kv_cache import pages_needed
@@ -249,6 +250,7 @@ def main():
                          "the run")
     ServeOptions.add_cli(ap)
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = (configs.get_smoke if args.smoke else configs.get)(args.arch)
     model = build_model(cfg)

@@ -398,10 +398,8 @@ def update_kv_cache(cache, new, slot):
                                        0, 0))
         return lax.cond(in_range, write, lambda c: c, c)
 
-    from ..sharding.compat import shard_map_compat
-    return shard_map_compat(local, mesh=mesh, in_specs=(spec, new_spec, P()),
-                            out_specs=spec, check_vma=False)(
-                                cache, new, slot)
+    return jax.shard_map(local, mesh=mesh, in_specs=(spec, new_spec, P()),
+                         out_specs=spec, check_vma=False)(cache, new, slot)
 
 
 def _gqa_expand_factor(cfg) -> int:
@@ -858,10 +856,8 @@ def moe_block(p, x, cfg):
         aux = lax.pmean(aux, "model")   # identical per model shard
         return y, aux
 
-    from ..sharding.compat import shard_map_compat
-    y, aux = shard_map_compat(local, mesh=mesh, in_specs=(pspec, xspec),
-                              out_specs=(xspec, P()),
-                              check_vma=False)(p, x)
+    y, aux = jax.shard_map(local, mesh=mesh, in_specs=(pspec, xspec),
+                           out_specs=(xspec, P()), check_vma=False)(p, x)
     return constrain(y, ("batch", "seq", "act_embed")), aux
 
 

@@ -89,7 +89,7 @@ def pages_needed(n_tokens: int, page_size: int) -> int:
 class PagedKVCache:
     def __init__(self, model, *, max_batch: int, n_pages: int,
                  page_size: int, max_pages_per_seq: int,
-                 prefix_sharing: bool = True):
+                 prefix_sharing: bool = True, sharding=None):
         cfg = model.cfg
         if not (model.scanned and model.first_dense == 0
                 and set(cfg.layer_kinds) == {"attn"}):
@@ -104,10 +104,13 @@ class PagedKVCache:
         self.max_batch = max_batch
         self.max_pages_per_seq = max_pages_per_seq
 
+        # allocated where the programs read it (``sharding``: a chip
+        # or the TP mesh layout; None: the default device) — never
+        # staged on the default device first
         L = cfg.n_layers
         shape = (L, n_pages, page_size, cfg.n_kv_heads, cfg.head_dim)
-        self.k_pages = jnp.zeros(shape, jnp.bfloat16)
-        self.v_pages = jnp.zeros(shape, jnp.bfloat16)
+        self.k_pages = jnp.zeros(shape, jnp.bfloat16, device=sharding)
+        self.v_pages = jnp.zeros(shape, jnp.bfloat16, device=sharding)
 
         # host-side bookkeeping
         self._free: List[int] = list(range(n_pages - 1, NULL_PAGE, -1))

@@ -19,7 +19,10 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import itertools
 from typing import Dict, List, Optional, Sequence
+
+import jax
 
 from .kv_cache import pages_needed
 from .router import ROUTER_POLICIES, RequestRouter
@@ -250,8 +253,6 @@ class ServeOptions:
         share one instance."""
         if not (self.spec_k and self.draft_config):
             return None
-        import jax
-
         from repro import configs
         from repro.models import build_model
 
@@ -274,7 +275,10 @@ class ServeOptions:
         ``max_replicas > 0`` — an ``ElasticController`` whose fleet
         tracks demand.  All replicas, including ones the controller
         adds later, share ONE program bundle (one compile cache
-        regardless of fleet size)."""
+        regardless of fleet size).  Without tensor parallelism, replica
+        i of a fleet is placed on local device i mod n, its params and
+        pages committed there; a lone engine, or a TP fleet (placed by
+        its mesh), stays on the default device."""
         if self.n_pages <= 0:
             raise ValueError("n_pages unresolved: pass it explicitly or "
                              "call sized_for(reqs) first")
@@ -297,7 +301,13 @@ class ServeOptions:
                 trace=bool(self.trace_out or self.metrics_interval),
                 metrics_interval=self.metrics_interval)
 
+        fleet = self.replicas > 1 or self.max_replicas > 0
+        devices = jax.local_devices()
+        placed = itertools.count()
+
         def mk():
+            device = (devices[next(placed) % len(devices)]
+                      if fleet and self.tp == 1 else None)
             return ServeEngine(
                 model, params, max_batch=self.batch,
                 n_pages=self.n_pages, page_size=self.page_size,
@@ -310,6 +320,7 @@ class ServeOptions:
                          else None),
                 fused=self.fused,
                 programs=programs,
+                device=device,
                 telemetry=tel)
 
         def wrap_faults(engines):

@@ -57,7 +57,6 @@ import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..launch.mesh import make_mesh
-from ..sharding.compat import shard_map_compat
 from ..sharding.rules import SERVE_TP_AXIS, serve_tp_spec
 from .step import (make_chunk_prefill_step, make_fused_step,
                    make_paged_decode_step, make_verify_step)
@@ -124,7 +123,7 @@ def _local_model(model, tp: int):
 class TPServePrograms:
     """Sharded counterpart of step.ServePrograms: same attribute
     surface (decode / chunk / verify callables with identical
-    signatures, prepare_params / prepare_pages hooks), so ServeEngine
+    signatures, prepare_params / page_sharding hooks), so ServeEngine
     uses either interchangeably — and N router replicas can share one
     instance to share one compile cache."""
 
@@ -146,7 +145,7 @@ class TPServePrograms:
         full_state = {"k_pages": PAGE_SPEC, "v_pages": PAGE_SPEC,
                       "page_tables": P(), "lengths": P()}
         kv_state = {"k_pages": PAGE_SPEC, "v_pages": PAGE_SPEC}
-        self.decode = jax.jit(shard_map_compat(
+        self.decode = jax.jit(jax.shard_map(
             make_paged_decode_step(self._local, tp_axis=SERVE_TP_AXIS),
             mesh=mesh, in_specs=(self._pspecs, full_state, P()),
             out_specs=(P(), full_state), check_vma=False))
@@ -154,7 +153,7 @@ class TPServePrograms:
         # n_valid) are per-row control metadata — replicated, like the
         # decode program's page tables; the heads of the gathered
         # context and the page scatter shard with kv_state
-        self.chunk = jax.jit(shard_map_compat(
+        self.chunk = jax.jit(jax.shard_map(
             make_chunk_prefill_step(self._local, tp_axis=SERVE_TP_AXIS),
             mesh=mesh,
             in_specs=(self._pspecs, kv_state, P(), P(), P(), P()),
@@ -168,7 +167,7 @@ class TPServePrograms:
         if self._verify is None:
             full_state = {"k_pages": PAGE_SPEC, "v_pages": PAGE_SPEC,
                           "page_tables": P(), "lengths": P()}
-            self._verify = jax.jit(shard_map_compat(
+            self._verify = jax.jit(jax.shard_map(
                 make_verify_step(self._local, tp_axis=SERVE_TP_AXIS),
                 mesh=self.mesh,
                 in_specs=(self._pspecs, full_state, P()),
@@ -184,7 +183,7 @@ class TPServePrograms:
         if self._fused is None:
             full_state = {"k_pages": PAGE_SPEC, "v_pages": PAGE_SPEC,
                           "page_tables": P(), "lengths": P()}
-            self._fused = jax.jit(shard_map_compat(
+            self._fused = jax.jit(jax.shard_map(
                 make_fused_step(self._local, tp_axis=SERVE_TP_AXIS),
                 mesh=self.mesh,
                 in_specs=(self._pspecs, full_state, P(), P(), P(), P(),
@@ -192,11 +191,12 @@ class TPServePrograms:
                 out_specs=((P(), P()), full_state), check_vma=False))
         return self._fused
 
-    def prepare_params(self, params):
-        """device_put ``params`` into the TP layout (cached by object
-        identity so router replicas sharing one params tree also share
-        one sharded copy; the original is kept referenced so a
-        recycled id can never alias a dead tree)."""
+    def prepare_params(self, params, device=None):
+        """device_put ``params`` into the TP layout on the mesh (cached
+        by object identity so router replicas sharing one params tree
+        also share one sharded copy; the original is kept referenced so
+        a recycled id can never alias a dead tree).  The mesh places
+        the engine, so ``device`` is ignored."""
         key = id(params)
         if key not in self._params_cache:
             shardings = jax.tree.map(
@@ -206,5 +206,5 @@ class TPServePrograms:
                 params, jax.device_put(params, shardings))
         return self._params_cache[key][1]
 
-    def prepare_pages(self, pages):
-        return jax.device_put(pages, NamedSharding(self.mesh, PAGE_SPEC))
+    def page_sharding(self, device=None):
+        return NamedSharding(self.mesh, PAGE_SPEC)

@@ -157,6 +157,7 @@ class ServeEngine:
                  programs: Optional[ServePrograms] = None,
                  tp: int = 1,
                  mesh=None,
+                 device=None,
                  telemetry: Optional[Telemetry] = None):
         if not model.supports_paged_decode():
             raise ValueError(f"{model.cfg.name}: paged decode unsupported "
@@ -181,14 +182,16 @@ class ServeEngine:
         self.programs = programs
         self.tp = programs.tp
         self.model = model
-        self.params = programs.prepare_params(params)
+        # ``device`` pins a single-device engine (a router replica) to
+        # its own chip: params and pages are committed there, so every
+        # program dispatch runs there
+        self.params = programs.prepare_params(params, device)
         self.eos_id = eos_id
         self.cache = PagedKVCache(model, max_batch=max_batch,
                                   n_pages=n_pages, page_size=page_size,
                                   max_pages_per_seq=max_pages_per_seq,
-                                  prefix_sharing=prefix_sharing)
-        self.cache.k_pages = programs.prepare_pages(self.cache.k_pages)
-        self.cache.v_pages = programs.prepare_pages(self.cache.v_pages)
+                                  prefix_sharing=prefix_sharing,
+                                  sharding=programs.page_sharding(device))
         self.max_batch = max_batch
         self.chunk_size = chunk_size
         # rows per chunked-prefill dispatch (the program's batch dim).

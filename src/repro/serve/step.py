@@ -163,7 +163,7 @@ class ServePrograms:
     ``TPServePrograms``; the engine treats the two interchangeably.
     """
 
-    tp = 1          # single-device: no mesh, params/pages used as-is
+    tp = 1          # single-device: no mesh
 
     def __init__(self, model):
         self.model = model
@@ -171,6 +171,7 @@ class ServePrograms:
         self.chunk = jax.jit(make_chunk_prefill_step(model))
         self._verify = None
         self._fused = None
+        self._params_cache: dict = {}
 
     @property
     def verify(self):
@@ -185,12 +186,28 @@ class ServePrograms:
             self._fused = jax.jit(make_fused_step(self.model))
         return self._fused
 
-    # sharding hooks (overridden by TPServePrograms)
-    def prepare_params(self, params):
-        return params
+    # placement hooks (overridden by TPServePrograms).  ``device`` is
+    # the engine's own chip (None: the default device); the programs
+    # run where their committed params and pages live, so router
+    # replicas on distinct devices share this one bundle.
+    def prepare_params(self, params, device=None):
+        """``params`` committed to ``device`` (one copy per (tree,
+        device), shared by every engine placed there; the original
+        stays referenced so a recycled id can never alias a dead
+        tree)."""
+        if device is None:
+            return params
+        key = (id(params), device)
+        if key not in self._params_cache:
+            self._params_cache[key] = (params,
+                                       jax.device_put(params, device))
+        return self._params_cache[key][1]
 
-    def prepare_pages(self, pages):
-        return pages
+    def page_sharding(self, device=None):
+        """Where the engine's page pool is allocated."""
+        if device is None:
+            return None
+        return jax.sharding.SingleDeviceSharding(device)
 
 
 def greedy_generate(model, params, prompt_batch, n_steps: int,

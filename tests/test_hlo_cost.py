@@ -13,18 +13,11 @@ def _compile(f, *args):
     return jax.jit(f).lower(*args).compile()
 
 
-def _xla_cost(c):
-    """``compiled.cost_analysis()`` returns a one-element list on the
-    pinned jax 0.4.37 and a bare dict on newer versions."""
-    ca = c.cost_analysis()
-    return ca[0] if isinstance(ca, (list, tuple)) else ca
-
-
 def test_matches_cost_analysis_on_plain_matmul():
     xs = jax.ShapeDtypeStruct((256, 256), jnp.float32)
     c = _compile(lambda a, b: a @ b, xs, xs)
     ours = hlo_cost.analyze_module(c.as_text(), 1)
-    theirs = _xla_cost(c)
+    theirs = c.cost_analysis()
     assert ours.flops == pytest.approx(theirs["flops"], rel=0.01)
 
 
@@ -40,7 +33,7 @@ def test_scan_flops_multiplied_by_trip_count():
     assert ours.flops == pytest.approx(want, rel=0.05)
     # XLA's own analysis undercounts by the trip count — the reason
     # this module exists:
-    assert _xla_cost(c)["flops"] < want / 6
+    assert c.cost_analysis()["flops"] < want / 6
 
 
 def test_scan_carry_bytes_not_inflated_by_buffer():

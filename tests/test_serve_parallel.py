@@ -244,3 +244,42 @@ def test_tp_validation_rejects_bad_configs():
     moe = build_model(configs.get_smoke("deepseek-moe-16b"))
     with pytest.raises(ValueError):
         validate_tp(moe, 2)
+
+
+def test_router_replicas_placed_on_their_own_devices():
+    """A tp=1 fleet from ServeOptions puts replica i's params and page
+    pool on device i (the pool allocated there, not moved), and each
+    replica's streams equal a lone engine's."""
+    print(run_devices(4, """
+        import dataclasses
+        import jax, numpy as np
+        from repro import configs
+        from repro.models import build_model
+        from repro.serve import Request, ServeOptions
+
+        cfg = configs.get_smoke("qwen3-0.6b")
+        model = build_model(cfg)
+        params = model.init(jax.random.PRNGKey(0))
+        rng = np.random.default_rng(2)
+        prompts = [rng.integers(0, cfg.vocab_size, size=(12,))
+                   .astype(np.int32) for _ in range(8)]
+
+        def trace():
+            return [Request(rid=i, prompt=p, max_new_tokens=5)
+                    for i, p in enumerate(prompts)]
+
+        opts = ServeOptions(batch=2, page_size=8,
+                            router_policy="round-robin").sized_for(trace())
+        want = {r.rid: list(r.generated)
+                for r in opts.build(model, params).run(trace())}
+        fleet = dataclasses.replace(opts, replicas=4).build(model, params)
+        got = {r.rid: list(r.generated) for r in fleet.run(trace())}
+        assert want == got, (want, got)
+        devs = jax.devices()
+        for i, eng in enumerate(fleet.replicas):
+            for arr in (eng.cache.k_pages, eng.cache.v_pages,
+                        *jax.tree.leaves(eng.params)):
+                assert arr.devices() == {devs[i]}, (i, arr.devices())
+        assert all(n == 2 for n in fleet.n_dispatched), fleet.n_dispatched
+        print("replica placement ok")
+    """))

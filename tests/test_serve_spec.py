@@ -117,17 +117,22 @@ def test_spec_engine_preemption_token_exact(qwen3):
     request recomputes (replay) and still matches the oracle."""
     cfg, model, params = qwen3
     rng = np.random.default_rng(11)
-    lens, gen = [30, 28, 18], 8
+    lens, gen = [16, 16, 11], 8
     prompts = [rng.integers(0, cfg.vocab_size, size=(L,)).astype(np.int32)
                for L in lens]
     oracle = oracles(model, params, prompts, gen)
-    # n_pages=9 runs the pool dry mid-speculation under the fused step's
-    # one-chunk-per-step admission pacing (13 did under the unfused one).
-    eng = ServeEngine(model, params, max_batch=3, n_pages=9,
-                      page_size=8, max_pages_per_seq=8,
+    # The pool forces a preemption whatever the weights draw: 5 usable
+    # pages of 8.  Both 16-token prompts are admitted and co-ingested
+    # in the first step (2 pages each + 1 spare page kept free at
+    # admission), so 1 page is left.  The first write of each request
+    # lands at position 16, on a third page: the older takes the last
+    # free page, the younger is evicted, however many drafts verify.
+    eng = ServeEngine(model, params, max_batch=2, prefill_batch=2,
+                      n_pages=6, page_size=8, max_pages_per_seq=8,
                       prefix_sharing=False, spec_k=4)
     done = eng.run([Request(rid=i, prompt=p, max_new_tokens=gen)
                     for i, p in enumerate(prompts)])
+    assert len(done) == len(prompts)
     assert sum(r.n_preemptions for r in done) >= 1
     assert_parity(done, oracle)
     eng.cache.check_invariants()
