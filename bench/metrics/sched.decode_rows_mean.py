@@ -1,0 +1,8 @@
+"""Scheduler: mean active decode rows per decode or fused dispatch
+issued in the window (replay dispatches left out), where the cell is
+judged on tokens per second."""
+from bench.lib.readers import decode_rows_mean
+
+
+def read(run):
+    return decode_rows_mean(run)
