@@ -4,6 +4,10 @@ Op-for-op the same math as ``components.decode_attention`` (bf16
 operands, f32 MXU accumulation, -1e30 masking) so the continuous-
 batching decode path stays token-exact against the contiguous-cache
 greedy oracle: gathered padding positions contribute exact zeros.
+
+Named scopes split the device time of the paged step programs
+(models/lm.py): the page gather is ``attn.gather``, scores, softmax and
+values ``attn.core``.
 """
 import math
 
@@ -14,8 +18,9 @@ import jax.numpy as jnp
 def gather_pages(pages, page_tables):
     """(P, ps, KVH, Dh) + (B, n) -> (B, n * ps, KVH, Dh)."""
     B, n = page_tables.shape
-    g = pages[page_tables]                       # (B, n, ps, KVH, Dh)
-    return g.reshape(B, n * pages.shape[1], *pages.shape[2:])
+    with jax.named_scope("attn.gather"):
+        g = pages[page_tables]                   # (B, n, ps, KVH, Dh)
+        return g.reshape(B, n * pages.shape[1], *pages.shape[2:])
 
 
 def paged_attention_ref(q, k_pages, v_pages, page_tables, lengths):
@@ -27,17 +32,18 @@ def paged_attention_ref(q, k_pages, v_pages, page_tables, lengths):
     G = H // KVH
     k = gather_pages(k_pages, page_tables)       # (B, S, KVH, Dh)
     v = gather_pages(v_pages, page_tables)
-    qh = q.reshape(B, KVH, G, Dh)
-    s = jnp.einsum("bkgd,bjkd->bkgj", qh, k,
-                   preferred_element_type=jnp.float32)
-    s = s * (1.0 / math.sqrt(Dh))
-    idx = jnp.arange(k.shape[1])
-    valid = idx[None, :] < lengths[:, None]      # (B, S)
-    s = jnp.where(valid[:, None, None], s, -1e30)
-    p = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("bkgj,bjkd->bkgd", p.astype(v.dtype), v,
-                     preferred_element_type=jnp.float32)
-    return out.reshape(B, H, Dh).astype(q.dtype)
+    with jax.named_scope("attn.core"):
+        qh = q.reshape(B, KVH, G, Dh)
+        s = jnp.einsum("bkgd,bjkd->bkgj", qh, k,
+                       preferred_element_type=jnp.float32)
+        s = s * (1.0 / math.sqrt(Dh))
+        idx = jnp.arange(k.shape[1])
+        valid = idx[None, :] < lengths[:, None]  # (B, S)
+        s = jnp.where(valid[:, None, None], s, -1e30)
+        p = jax.nn.softmax(s, axis=-1)
+        out = jnp.einsum("bkgj,bjkd->bkgd", p.astype(v.dtype), v,
+                         preferred_element_type=jnp.float32)
+        return out.reshape(B, H, Dh).astype(q.dtype)
 
 
 def paged_verify_attention_ref(q, k_pages, v_pages, page_tables, lengths):
@@ -61,15 +67,16 @@ def paged_verify_attention_ref(q, k_pages, v_pages, page_tables, lengths):
     G = H // KVH
     k = gather_pages(k_pages, page_tables)       # (B, S, KVH, Dh)
     v = gather_pages(v_pages, page_tables)
-    qh = q.reshape(B, T, KVH, G, Dh)
-    s = jnp.einsum("btkgd,bjkd->btkgj", qh, k,
-                   preferred_element_type=jnp.float32)
-    s = s * (1.0 / math.sqrt(Dh))
-    idx = jnp.arange(k.shape[1])
-    q_pos = lengths[:, None] + jnp.arange(T)[None, :]        # (B, T)
-    valid = idx[None, None, :] < (q_pos + 1)[:, :, None]     # (B, T, S)
-    s = jnp.where(valid[:, :, None, None], s, -1e30)
-    p = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("btkgj,bjkd->btkgd", p.astype(v.dtype), v,
-                     preferred_element_type=jnp.float32)
-    return out.reshape(B, T, H, Dh).astype(q.dtype)
+    with jax.named_scope("attn.core"):
+        qh = q.reshape(B, T, KVH, G, Dh)
+        s = jnp.einsum("btkgd,bjkd->btkgj", qh, k,
+                       preferred_element_type=jnp.float32)
+        s = s * (1.0 / math.sqrt(Dh))
+        idx = jnp.arange(k.shape[1])
+        q_pos = lengths[:, None] + jnp.arange(T)[None, :]    # (B, T)
+        valid = idx[None, None, :] < (q_pos + 1)[:, :, None]  # (B, T, S)
+        s = jnp.where(valid[:, :, None, None], s, -1e30)
+        p = jax.nn.softmax(s, axis=-1)
+        out = jnp.einsum("btkgj,bjkd->btkgd", p.astype(v.dtype), v,
+                         preferred_element_type=jnp.float32)
+        return out.reshape(B, T, H, Dh).astype(q.dtype)

@@ -557,11 +557,12 @@ def paged_attention_block(p, x, cfg, *, positions, k_pages, v_pages,
     assert S == 1, "paged path is decode-only"
     q, k, v = _project_qkv(p, x, cfg, positions)
     ps = k_pages.shape[1]
-    bidx = jnp.arange(B)
-    pidx = page_table[bidx, lengths // ps]            # (B,)
-    slot = lengths % ps
-    k_pages = k_pages.at[pidx, slot].set(k[:, 0].astype(k_pages.dtype))
-    v_pages = v_pages.at[pidx, slot].set(v[:, 0].astype(v_pages.dtype))
+    with jax.named_scope("attn.kv_write"):
+        bidx = jnp.arange(B)
+        pidx = page_table[bidx, lengths // ps]        # (B,)
+        slot = lengths % ps
+        k_pages = k_pages.at[pidx, slot].set(k[:, 0].astype(k_pages.dtype))
+        v_pages = v_pages.at[pidx, slot].set(v[:, 0].astype(v_pages.dtype))
     out = paged_attention_ref(q[:, 0], k_pages, v_pages, page_table,
                               lengths + 1)
     out = _tp_gather_heads(out, tp_axis, axis=1)       # (B, H, Dh)
@@ -595,9 +596,10 @@ def paged_verify_attention_block(p, x, cfg, *, positions, k_pages,
     B, T, D = x.shape
     q, k, v = _project_qkv(p, x, cfg, positions)
     ps = k_pages.shape[1]
-    pidx, slot = verify_scatter_targets(lengths, page_table, T, ps)
-    k_pages = k_pages.at[pidx, slot].set(k.astype(k_pages.dtype))
-    v_pages = v_pages.at[pidx, slot].set(v.astype(v_pages.dtype))
+    with jax.named_scope("attn.kv_write"):
+        pidx, slot = verify_scatter_targets(lengths, page_table, T, ps)
+        k_pages = k_pages.at[pidx, slot].set(k.astype(k_pages.dtype))
+        v_pages = v_pages.at[pidx, slot].set(v.astype(v_pages.dtype))
     out = paged_verify_attention_ref(q, k_pages, v_pages, page_table,
                                      lengths)
     out = _tp_gather_heads(out, tp_axis, axis=2)       # (B, T, H, Dh)
@@ -642,16 +644,18 @@ def paged_chunk_attention_block(p, x, cfg, *, positions, starts, n_valid,
     """
     B, C, D = x.shape
     q, k, v = _project_qkv(p, x, cfg, positions)
-    kc = k_pages[table_rows].reshape(B, -1, *k_pages.shape[2:])
-    vc = v_pages[table_rows].reshape(B, -1, *v_pages.shape[2:])
-    kc = jnp.pad(kc, ((0, 0), (0, C), (0, 0), (0, 0)))
-    vc = jnp.pad(vc, ((0, 0), (0, C), (0, 0), (0, 0)))
-    overlay = jax.vmap(
-        lambda buf, new, s: lax.dynamic_update_slice(buf, new, (s, 0, 0)))
-    kc = overlay(kc, k.astype(kc.dtype), starts)
-    vc = overlay(vc, v.astype(vc.dtype), starts)
-    out = flash_attention(q, kc, vc, causal=True,
-                          kv_chunk=cfg.attn_kv_chunk, q_offset=starts)
+    with jax.named_scope("attn.gather"):
+        kc = k_pages[table_rows].reshape(B, -1, *k_pages.shape[2:])
+        vc = v_pages[table_rows].reshape(B, -1, *v_pages.shape[2:])
+        kc = jnp.pad(kc, ((0, 0), (0, C), (0, 0), (0, 0)))
+        vc = jnp.pad(vc, ((0, 0), (0, C), (0, 0), (0, 0)))
+        overlay = jax.vmap(lambda buf, new, s:
+                           lax.dynamic_update_slice(buf, new, (s, 0, 0)))
+        kc = overlay(kc, k.astype(kc.dtype), starts)
+        vc = overlay(vc, v.astype(vc.dtype), starts)
+    with jax.named_scope("attn.core"):
+        out = flash_attention(q, kc, vc, causal=True,
+                              kv_chunk=cfg.attn_kv_chunk, q_offset=starts)
     out = _tp_gather_heads(out, tp_axis, axis=2)       # (B, C, H, Dh)
     out = out.reshape(B, C, -1)
     out = out @ p["wo"].astype(out.dtype)

@@ -161,12 +161,14 @@ class DecoderLM:
     # ------------------------------------------------------------------
     def _embed_inputs(self, params, batch, dtype):
         cfg = self.cfg
-        x = C.embed_tokens(params["embed"], batch["tokens"], cfg, dtype)
-        if cfg.n_patches and "patch_embeds" in batch:
-            # VLM stub frontend: precomputed patch embeddings replace the
-            # leading placeholder tokens (brief: frontend is a stub).
-            pe = batch["patch_embeds"].astype(dtype)
-            x = lax.dynamic_update_slice(x, pe, (0, 0, 0))
+        with jax.named_scope("embed"):
+            x = C.embed_tokens(params["embed"], batch["tokens"], cfg, dtype)
+            if cfg.n_patches and "patch_embeds" in batch:
+                # VLM stub frontend: precomputed patch embeddings replace
+                # the leading placeholder tokens (brief: frontend is a
+                # stub).
+                pe = batch["patch_embeds"].astype(dtype)
+                x = lax.dynamic_update_slice(x, pe, (0, 0, 0))
         return x
 
     def _positions(self, batch):
@@ -405,6 +407,61 @@ class DecoderLM:
             "lengths": ParamSpec((batch,), ("batch",), jnp.int32),
         }
 
+    # The paged step programs name their parts with ``jax.named_scope``
+    # (a device op's scope path in the profiler trace, e.g.
+    # ``jit(fused_step)/while/body/attn/attn.core/...``): ``embed``;
+    # per layer ``norm``, ``attn`` (children ``attn.gather``,
+    # ``attn.core``, ``attn.kv_write``) and ``mlp``; after the layer
+    # scan ``kv_scatter``, ``norm`` and ``unembed``; ``sample`` in
+    # serve/step.py.  The scan's own slicing of its inputs (the stacked
+    # weights and page pools) and stacking of its outputs stay outside
+    # every scope, so the layer loop's unscoped ops are that traffic.
+    def _paged_layer(self, lp, x, attend, tp_axis):
+        """One layer of a paged step program on the residual stream
+        ``x``: ``attend(h)`` is the attention sub-layer on the normed
+        input, returning ``(out, *extra)``.  Returns (x, extra)."""
+        cfg = self.cfg
+        with jax.named_scope("norm"):
+            h = C.apply_norm(lp["ln1"], x, cfg.norm_kind, cfg.norm_eps)
+        with jax.named_scope("attn"):
+            mix, *extra = attend(h)
+            x = x + mix
+        with jax.named_scope("norm"):
+            h = C.apply_norm(lp["ln2"], x, cfg.norm_kind, cfg.norm_eps)
+        with jax.named_scope("mlp"):
+            if cfg.moe is not None:
+                f, _ = C.moe_block(lp["ffn"], h, cfg)
+            else:
+                f = C.mlp_block(lp["ffn"], h, cfg, tp_axis=tp_axis)
+            x = x + f
+        return x, extra
+
+    def _paged_logits(self, params, x, n_valid=None):
+        """Final norm and unembedding of ``x`` (B, T, D); with
+        ``n_valid`` (B,), of each row's last valid token only."""
+        cfg = self.cfg
+        with jax.named_scope("norm"):
+            x = C.apply_norm(params["final_norm"], x, cfg.norm_kind,
+                             cfg.norm_eps)
+        with jax.named_scope("unembed"):
+            if n_valid is not None:
+                x = jnp.take_along_axis(
+                    x, jnp.maximum(n_valid - 1, 0)[:, None, None], axis=1)
+            return C.unembed(params["embed"], x, cfg)
+
+    @staticmethod
+    def _kv_scatter(k_pages, v_pages, ks, vs, starts, n_valid, table_rows):
+        """Persist every prefill row's chunk K/V (``ks``/``vs``: (L, B,
+        C, KVH, Dh), stacked by the layer scan) for every layer in one
+        stacked scatter.  Tokens t >= n_valid[b] are routed to null page
+        0; write-target pages are private per row (COW at admission), so
+        co-ingested rows never scatter into each other."""
+        with jax.named_scope("kv_scatter"):
+            pid, slot = C.chunk_scatter_targets(
+                starts, n_valid, table_rows, ks.shape[2], k_pages.shape[2])
+            return (k_pages.at[:, pid, slot].set(ks.astype(k_pages.dtype)),
+                    v_pages.at[:, pid, slot].set(vs.astype(v_pages.dtype)))
+
     def prefill_chunk_paged(self, params, state, tokens, table_rows,
                             starts, n_valid, tp_axis=None):
         """Ingest one prompt chunk each for up to B requests into the
@@ -443,42 +500,22 @@ class DecoderLM:
         positions = starts[:, None] + jnp.arange(n, dtype=jnp.int32)[None]
         x = self._embed_inputs(
             params, {"tokens": tokens, "positions": positions}, dtype)
-        use_moe = cfg.moe is not None
 
         def body(x, inp):
             lp, kp, vp = inp
-            h = C.apply_norm(lp["ln1"], x, cfg.norm_kind, cfg.norm_eps)
-            mix, k, v = C.paged_chunk_attention_block(
-                lp["mix"], h, cfg, positions=positions, starts=starts,
-                n_valid=n_valid, k_pages=kp, v_pages=vp,
-                table_rows=table_rows, tp_axis=tp_axis)
-            x = x + mix
-            h2 = C.apply_norm(lp["ln2"], x, cfg.norm_kind, cfg.norm_eps)
-            if use_moe:
-                f, _ = C.moe_block(lp["ffn"], h2, cfg)
-            else:
-                f = C.mlp_block(lp["ffn"], h2, cfg, tp_axis=tp_axis)
-            return x + f, (k, v)
+            return self._paged_layer(
+                lp, x, lambda h: C.paged_chunk_attention_block(
+                    lp["mix"], h, cfg, positions=positions, starts=starts,
+                    n_valid=n_valid, k_pages=kp, v_pages=vp,
+                    table_rows=table_rows, tp_axis=tp_axis), tp_axis)
 
         x, (ks, vs) = lax.scan(
             body, x, (params["layers"], state["k_pages"],
                       state["v_pages"]))
-        # persist every row's chunk K/V for every layer in one stacked
-        # scatter (tokens t >= n_valid[b] are routed to null page 0;
-        # write-target pages are private per row — COW at admission —
-        # so co-ingested rows can never scatter into each other)
-        ps_ = state["k_pages"].shape[2]
-        pid, slot = C.chunk_scatter_targets(starts, n_valid, table_rows,
-                                            n, ps_)
-        k_pages = state["k_pages"].at[:, pid, slot].set(
-            ks.astype(state["k_pages"].dtype))
-        v_pages = state["v_pages"].at[:, pid, slot].set(
-            vs.astype(state["v_pages"].dtype))
-        x = C.apply_norm(params["final_norm"], x, cfg.norm_kind,
-                         cfg.norm_eps)
-        last = jnp.take_along_axis(
-            x, jnp.maximum(n_valid - 1, 0)[:, None, None], axis=1)
-        logits = C.unembed(params["embed"], last, cfg)
+        k_pages, v_pages = self._kv_scatter(
+            state["k_pages"], state["v_pages"], ks, vs, starts, n_valid,
+            table_rows)
+        logits = self._paged_logits(params, x, n_valid)
         return logits[:, 0], {"k_pages": k_pages, "v_pages": v_pages}
 
     def decode_step_paged(self, params, state, tokens, tp_axis=None):
@@ -503,29 +540,19 @@ class DecoderLM:
         positions = lengths[:, None].astype(jnp.int32)
         x = self._embed_inputs(
             params, {"tokens": tokens, "positions": positions}, dtype)
-        use_moe = cfg.moe is not None
 
         def body(x, inp):
             lp, kp, vp = inp
-            h = C.apply_norm(lp["ln1"], x, cfg.norm_kind, cfg.norm_eps)
-            mix, kp, vp = C.paged_attention_block(
-                lp["mix"], h, cfg, positions=positions, k_pages=kp,
-                v_pages=vp, page_table=tables, lengths=lengths,
-                tp_axis=tp_axis)
-            x = x + mix
-            h2 = C.apply_norm(lp["ln2"], x, cfg.norm_kind, cfg.norm_eps)
-            if use_moe:
-                f, _ = C.moe_block(lp["ffn"], h2, cfg)
-            else:
-                f = C.mlp_block(lp["ffn"], h2, cfg, tp_axis=tp_axis)
-            return x + f, (kp, vp)
+            return self._paged_layer(
+                lp, x, lambda h: C.paged_attention_block(
+                    lp["mix"], h, cfg, positions=positions, k_pages=kp,
+                    v_pages=vp, page_table=tables, lengths=lengths,
+                    tp_axis=tp_axis), tp_axis)
 
         x, (k_pages, v_pages) = lax.scan(
             body, x, (params["layers"], state["k_pages"],
                       state["v_pages"]))
-        x = C.apply_norm(params["final_norm"], x, cfg.norm_kind,
-                         cfg.norm_eps)
-        logits = C.unembed(params["embed"], x, cfg)
+        logits = self._paged_logits(params, x)
         return logits[:, 0], {"k_pages": k_pages, "v_pages": v_pages,
                               "page_tables": tables,
                               "lengths": lengths + 1}
@@ -563,29 +590,19 @@ class DecoderLM:
                      + jnp.arange(T, dtype=jnp.int32)[None, :])
         x = self._embed_inputs(
             params, {"tokens": tokens, "positions": positions}, dtype)
-        use_moe = cfg.moe is not None
 
         def body(x, inp):
             lp, kp, vp = inp
-            h = C.apply_norm(lp["ln1"], x, cfg.norm_kind, cfg.norm_eps)
-            mix, kp, vp = C.paged_verify_attention_block(
-                lp["mix"], h, cfg, positions=positions, k_pages=kp,
-                v_pages=vp, page_table=tables, lengths=lengths,
-                tp_axis=tp_axis)
-            x = x + mix
-            h2 = C.apply_norm(lp["ln2"], x, cfg.norm_kind, cfg.norm_eps)
-            if use_moe:
-                f, _ = C.moe_block(lp["ffn"], h2, cfg)
-            else:
-                f = C.mlp_block(lp["ffn"], h2, cfg, tp_axis=tp_axis)
-            return x + f, (kp, vp)
+            return self._paged_layer(
+                lp, x, lambda h: C.paged_verify_attention_block(
+                    lp["mix"], h, cfg, positions=positions, k_pages=kp,
+                    v_pages=vp, page_table=tables, lengths=lengths,
+                    tp_axis=tp_axis), tp_axis)
 
         x, (k_pages, v_pages) = lax.scan(
             body, x, (params["layers"], state["k_pages"],
                       state["v_pages"]))
-        x = C.apply_norm(params["final_norm"], x, cfg.norm_kind,
-                         cfg.norm_eps)
-        logits = C.unembed(params["embed"], x, cfg)
+        logits = self._paged_logits(params, x)
         return logits, {"k_pages": k_pages, "v_pages": v_pages,
                         "page_tables": tables, "lengths": lengths}
 
@@ -640,7 +657,6 @@ class DecoderLM:
         xp = self._embed_inputs(
             params, {"tokens": p_tokens, "positions": p_positions},
             dtype)
-        use_moe = cfg.moe is not None
 
         def body(carry, inp):
             xd, xp = carry
@@ -649,35 +665,20 @@ class DecoderLM:
             # (decode's writes are not in any prefill table — see
             # disjointness above — so the order is unobservable);
             # its K/V persists in one stacked scatter after the scan
-            hp = C.apply_norm(lp["ln1"], xp, cfg.norm_kind, cfg.norm_eps)
-            mixp, k, v = C.paged_chunk_attention_block(
-                lp["mix"], hp, cfg, positions=p_positions,
-                starts=p_starts, n_valid=p_n_valid, k_pages=kp,
-                v_pages=vp, table_rows=p_table_rows, tp_axis=tp_axis)
-            xp = xp + mixp
-            hp2 = C.apply_norm(lp["ln2"], xp, cfg.norm_kind,
-                               cfg.norm_eps)
-            if use_moe:
-                fp, _ = C.moe_block(lp["ffn"], hp2, cfg)
-            else:
-                fp = C.mlp_block(lp["ffn"], hp2, cfg, tp_axis=tp_axis)
-            xp = xp + fp
+            xp, (k, v) = self._paged_layer(
+                lp, xp, lambda h: C.paged_chunk_attention_block(
+                    lp["mix"], h, cfg, positions=p_positions,
+                    starts=p_starts, n_valid=p_n_valid, k_pages=kp,
+                    v_pages=vp, table_rows=p_table_rows,
+                    tp_axis=tp_axis), tp_axis)
             # decode/verify rows: T == 1 verification IS the decode
             # step bit for bit (paged_verify_attention_block), so one
             # body serves both widths
-            hd = C.apply_norm(lp["ln1"], xd, cfg.norm_kind, cfg.norm_eps)
-            mixd, kp, vp = C.paged_verify_attention_block(
-                lp["mix"], hd, cfg, positions=d_positions, k_pages=kp,
-                v_pages=vp, page_table=tables, lengths=lengths,
-                tp_axis=tp_axis)
-            xd = xd + mixd
-            hd2 = C.apply_norm(lp["ln2"], xd, cfg.norm_kind,
-                               cfg.norm_eps)
-            if use_moe:
-                fd, _ = C.moe_block(lp["ffn"], hd2, cfg)
-            else:
-                fd = C.mlp_block(lp["ffn"], hd2, cfg, tp_axis=tp_axis)
-            xd = xd + fd
+            xd, (kp, vp) = self._paged_layer(
+                lp, xd, lambda h: C.paged_verify_attention_block(
+                    lp["mix"], h, cfg, positions=d_positions, k_pages=kp,
+                    v_pages=vp, page_table=tables, lengths=lengths,
+                    tp_axis=tp_axis), tp_axis)
             return (xd, xp), (kp, vp, k, v)
 
         (xd, xp), (k_pages, v_pages, ks, vs) = lax.scan(
@@ -686,19 +687,10 @@ class DecoderLM:
         # persist the prefill chunks' K/V into the decode-updated pages
         # (disjoint write targets, so this commutes with the decode
         # writes already applied in the scan)
-        ps_ = state["k_pages"].shape[2]
-        pid, slot = C.chunk_scatter_targets(p_starts, p_n_valid,
-                                            p_table_rows, Cn, ps_)
-        k_pages = k_pages.at[:, pid, slot].set(ks.astype(k_pages.dtype))
-        v_pages = v_pages.at[:, pid, slot].set(vs.astype(v_pages.dtype))
-        xd = C.apply_norm(params["final_norm"], xd, cfg.norm_kind,
-                          cfg.norm_eps)
-        d_logits = C.unembed(params["embed"], xd, cfg)
-        xp = C.apply_norm(params["final_norm"], xp, cfg.norm_kind,
-                          cfg.norm_eps)
-        last = jnp.take_along_axis(
-            xp, jnp.maximum(p_n_valid - 1, 0)[:, None, None], axis=1)
-        p_logits = C.unembed(params["embed"], last, cfg)[:, 0]
+        k_pages, v_pages = self._kv_scatter(
+            k_pages, v_pages, ks, vs, p_starts, p_n_valid, p_table_rows)
+        d_logits = self._paged_logits(params, xd)
+        p_logits = self._paged_logits(params, xp, p_n_valid)[:, 0]
         return (d_logits, p_logits), {
             "k_pages": k_pages, "v_pages": v_pages,
             "page_tables": tables, "lengths": lengths}

@@ -87,7 +87,7 @@ class ServeBackend(Protocol):
 
     def submit(self, req) -> None: ...
 
-    def step(self, now: float = float("inf")) -> bool: ...
+    def step(self, now: float = float("inf"), clock=None) -> bool: ...
 
     def drain_events(self) -> List[StreamEvent]: ...
 

@@ -296,7 +296,7 @@ class ElasticController:
                             kind="repair", live=self.router.n_live)
 
     # -------------------------------------------------------------- step
-    def step(self, now: float = float("inf")) -> bool:
+    def step(self, now: float = float("inf"), clock=None) -> bool:
         """One fleet iteration: run the control loop every
         ``scale_interval``-th call, repair crash losses, then one
         router step (which executes any drain the control round just
@@ -306,7 +306,7 @@ class ElasticController:
             self._control(now)
         self._maybe_repair(now)
         self._tick += 1
-        return self.router.step(now)
+        return self.router.step(now, clock)
 
     # ------------------------------------------------------------ stats
     def stats(self) -> Dict[str, float]:
@@ -332,9 +332,12 @@ class ElasticController:
         for r in requests:
             self.submit(r)
         t0 = time.perf_counter()
+
+        def clock():
+            return time.perf_counter() - t0
         while True:
-            now = (time.perf_counter() - t0) if realtime else float("inf")
-            if not self.step(now=now):
+            now = clock() if realtime else float("inf")
+            if not self.step(now=now, clock=clock if realtime else None):
                 break
             if realtime and self.router.queue \
                     and not any(e.n_inflight for e in self.replicas):

@@ -120,7 +120,7 @@ class FaultInjector:
                 < self.stall_at + self.stall_for)
 
     # ---------------------------------------------------- ServeBackend
-    def step(self, now: float = float("inf")) -> bool:
+    def step(self, now: float = float("inf"), clock=None) -> bool:
         self._alive()
         self.n_steps += 1
         if self.crash_at is not None and self.n_steps >= self.crash_at:
@@ -131,7 +131,7 @@ class FaultInjector:
             # wedged: no dispatch, no events, no progress — but the
             # process answers, so report busy while holding work
             return bool(self._backend.n_inflight)
-        return self._backend.step(now)
+        return self._backend.step(now, clock)
 
     def submit(self, req) -> None:
         self._alive()

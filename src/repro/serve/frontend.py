@@ -448,7 +448,10 @@ class ServeFrontend:
         self._refill(self._now)
         self._dispatch(self._now)
         if self.backend.n_inflight:
-            self.backend.step(self._now)
+            if self.realtime:   # first tokens read the clock as banked
+                self.backend.step(self._now, clock=lambda: self.clock)
+            else:               # TTFT in whole pump steps
+                self.backend.step(self._now)
             for ev in self.backend.drain_events():
                 self._route(ev)
         return self.busy

@@ -567,11 +567,12 @@ class RequestRouter:
             self._harvest(i)
 
     # ------------------------------------------------------------- step
-    def step(self, now: float = float("inf")) -> bool:
+    def step(self, now: float = float("inf"), clock=None) -> bool:
         """One router iteration: execute pending drains (migrating
         their requests), place every arrived queued request a replica
         will take (FIFO), then pump one engine step on every replica
-        with work.  Returns True while anything is queued or in
+        with work (``clock`` goes to each, as ``ServeEngine.step``
+        reads it).  Returns True while anything is queued or in
         flight."""
         self._last_now = (float(now) if now != float("inf")
                           else self._last_now + 1.0)
@@ -599,7 +600,7 @@ class RequestRouter:
         for i, eng in enumerate(self.replicas):
             if eng.n_inflight:
                 try:
-                    eng.step(now)
+                    eng.step(now, clock)
                 except ReplicaFailure:
                     failed.append((i, "crash"))
                     continue
@@ -659,9 +660,12 @@ class RequestRouter:
         for r in requests:
             self.submit(r)
         t0 = time.perf_counter()
+
+        def clock():
+            return time.perf_counter() - t0
         while True:
-            now = (time.perf_counter() - t0) if realtime else float("inf")
-            if not self.step(now=now):
+            now = clock() if realtime else float("inf")
+            if not self.step(now=now, clock=clock if realtime else None):
                 break
             if realtime and self.queue \
                     and not any(e.n_inflight for e in self.replicas):

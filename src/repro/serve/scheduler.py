@@ -88,7 +88,7 @@ from .kv_cache import PagedKVCache
 from .spec import PromptLookupDrafter
 from .step import ServePrograms
 from .telemetry import (SpanEvent, Telemetry, expose_counters,
-                        merge_stats, next_uid)
+                        merge_stats, next_uid, phase)
 
 __all__ = ["Request", "ServeEngine", "SLO_CLASSES", "default_bucket_edges"]
 
@@ -254,6 +254,7 @@ class ServeEngine:
             n, component="engine", replica=self.uid)
             for n in _ENGINE_COUNTERS}
         self._now = 0.0              # last sanitized step clock
+        self._clock = None           # the caller's clock, this step
         self._last_decode_rows = 0   # rows in the last decode round
 
     # --------------------------------------------------------- frontend
@@ -537,21 +538,24 @@ class ServeEngine:
         batched program dispatch; promote rows whose chunk completes
         their prompt (_prefill_inputs / _finish_prefill carry the
         exactness argument)."""
-        tokens, tables, starts, valids, metas = \
-            self._prefill_inputs(group)
-        state = {"k_pages": self.cache.k_pages,
-                 "v_pages": self.cache.v_pages}
-        tok, state = self._chunk(self.params, state,
-                                 jax.numpy.asarray(tokens),
-                                 jax.numpy.asarray(tables),
-                                 jax.numpy.asarray(starts),
-                                 jax.numpy.asarray(valids))
+        with phase("serve.inputs"):
+            tokens, tables, starts, valids, metas = \
+                self._prefill_inputs(group)
+            state = {"k_pages": self.cache.k_pages,
+                     "v_pages": self.cache.v_pages}
+            args = [jax.numpy.asarray(a)
+                    for a in (tokens, tables, starts, valids)]
+        with phase("serve.dispatch"):
+            tok, state = self._chunk(self.params, state, *args)
         self.cache.k_pages = state["k_pages"]
         self.cache.v_pages = state["v_pages"]
         self._c["n_prefill_dispatches"].inc()
         self._c["n_prefill_chunks"].inc(len(metas))
         self._c["n_total_dispatches"].inc()
-        self._finish_prefill(metas, np.asarray(tok), now)
+        with phase("serve.wait"):
+            tok = np.asarray(tok)
+        with phase("serve.bank"):
+            self._finish_prefill(metas, tok, now)
 
     def _finish_prefill(self, metas, tok, now: float) -> None:
         """Advance and promote the rows of a completed prefill dispatch
@@ -605,7 +609,11 @@ class ServeEngine:
                                replica=self.uid,
                                n=int(first_token))
             if req.ttft is None:
-                req.ttft = now - req.arrival
+                # the token exists once this step banks it: read the
+                # caller's clock now where it lent one, else ``now``
+                # (the step's start, e.g. a step counter)
+                req.ttft = ((now if self._clock is None
+                             else self._clock()) - req.arrival)
                 if req.ttft != float("inf"):
                     self.tel.registry.histogram(
                         "ttft", tenant=req.tenant,
@@ -623,22 +631,24 @@ class ServeEngine:
         slots' decode growth can consume it), so replay makes room the
         same way the decode loop does — never by evicting itself."""
         for t in tokens:
-            while not self.cache.ensure_headroom(slot):
-                if not self._make_room(now, exclude=slot):
-                    raise RuntimeError(
-                        "single request exceeds total page budget")
-            toks = np.zeros((self.max_batch, 1), np.int32)
-            toks[slot, 0] = t
-            tables = np.zeros_like(self.cache.page_tables)
-            tables[slot] = self.cache.page_tables[slot]
-            lengths = np.zeros_like(self.cache.lengths)
-            lengths[slot] = self.cache.lengths[slot]
-            state = {"k_pages": self.cache.k_pages,
-                     "v_pages": self.cache.v_pages,
-                     "page_tables": jax.numpy.asarray(tables),
-                     "lengths": jax.numpy.asarray(lengths)}
-            _, state = self._decode(self.params, state,
-                                    jax.numpy.asarray(toks))
+            with phase("serve.headroom"):
+                while not self.cache.ensure_headroom(slot):
+                    if not self._make_room(now, exclude=slot):
+                        raise RuntimeError(
+                            "single request exceeds total page budget")
+            with phase("serve.replay"):
+                toks = np.zeros((self.max_batch, 1), np.int32)
+                toks[slot, 0] = t
+                tables = np.zeros_like(self.cache.page_tables)
+                tables[slot] = self.cache.page_tables[slot]
+                lengths = np.zeros_like(self.cache.lengths)
+                lengths[slot] = self.cache.lengths[slot]
+                state = {"k_pages": self.cache.k_pages,
+                         "v_pages": self.cache.v_pages,
+                         "page_tables": jax.numpy.asarray(tables),
+                         "lengths": jax.numpy.asarray(lengths)}
+                _, state = self._decode(self.params, state,
+                                        jax.numpy.asarray(toks))
             self.cache.k_pages = state["k_pages"]
             self.cache.v_pages = state["v_pages"]
             self.cache.lengths[slot] += 1
@@ -664,13 +674,14 @@ class ServeEngine:
         youngest-preemption) on pressure; slots evicted mid-loop simply
         drop out of ``self.active``.  ``window`` maps slot -> tokens
         about to be written (missing slots default to 1)."""
-        for slot in sorted(self.active):
-            need = window.get(slot, 1)
-            while slot in self.active and \
-                    not self.cache.ensure_headroom(slot, need):
-                if not self._make_room(now):
-                    raise RuntimeError(
-                        "single request exceeds total page budget")
+        with phase("serve.headroom"):
+            for slot in sorted(self.active):
+                need = window.get(slot, 1)
+                while slot in self.active and \
+                        not self.cache.ensure_headroom(slot, need):
+                    if not self._make_room(now):
+                        raise RuntimeError(
+                            "single request exceeds total page budget")
 
     def _masked_state(self) -> dict:
         """Device state for a batched program with non-DECODING rows
@@ -707,15 +718,17 @@ class ServeEngine:
         if self.spec_k > 0:
             k = self.spec_k
             drafts: Dict[int, List[int]] = {}
-            for slot, req in self.active.items():
-                # cap the draft so even full acceptance cannot outrun
-                # max_new_tokens — which also keeps every speculative
-                # write inside the page budget submit() admitted the
-                # request under
-                cap = min(k, req.max_new_tokens - len(req.generated) - 1)
-                d = self.drafter.propose(slot, req, cap) if cap > 0 \
-                    else []
-                drafts[slot] = [int(t) for t in d[:max(cap, 0)]]
+            with phase("serve.inputs"):
+                for slot, req in self.active.items():
+                    # cap the draft so even full acceptance cannot
+                    # outrun max_new_tokens — which also keeps every
+                    # speculative write inside the page budget submit()
+                    # admitted the request under
+                    cap = min(k,
+                              req.max_new_tokens - len(req.generated) - 1)
+                    d = self.drafter.propose(slot, req, cap) if cap > 0 \
+                        else []
+                    drafts[slot] = [int(t) for t in d[:max(cap, 0)]]
             # page headroom for every position this row can confirm
             # (n_draft + 1 writes).  Padded verify positions past the
             # window land on the null page or on this slot's own
@@ -735,23 +748,23 @@ class ServeEngine:
             self._ensure_headroom_all(now, {})
             if not self.active:          # pressure evicted everyone
                 return None
-        tokens = np.zeros((self.max_batch, T), np.int32)
-        for slot, req in self.active.items():
-            tokens[slot, 0] = req.generated[-1]
-            d = drafts.get(slot, [])
-            tokens[slot, 1:1 + len(d)] = d
+        with phase("serve.inputs"):
+            tokens = np.zeros((self.max_batch, T), np.int32)
+            for slot, req in self.active.items():
+                tokens[slot, 0] = req.generated[-1]
+                d = drafts.get(slot, [])
+                tokens[slot, 1:1 + len(d)] = d
         return tokens, drafts, any_draft
 
     def _apply_decode(self, nxt, drafts, any_draft, now: float) -> None:
         """Bank one decode/verify round's token output ``nxt``
-        ((B, 1) or (B, T), host- or device-side).  The acceptance loop
+        ((B, 1) or (B, T), host-side).  The acceptance loop
         is the unified form: with no drafts it degenerates to appending
         row token 0 (a = 0, the eos truncation is a no-op on a single
         token), which is exactly the plain decode bank."""
         self._c["n_decode_steps"].inc()
         self._c["n_spec_rounds"].inc(int(any_draft))
         self._last_decode_rows = len(self.active)
-        nxt = np.asarray(nxt)
         for slot in list(self.active):
             req = self.active[slot]
             d, row = drafts.get(slot, []), nxt[slot]
@@ -786,12 +799,18 @@ class ServeEngine:
                       now: float) -> None:
         """Unfused decode/verify dispatch over the prepared round."""
         program = self._verify if tokens.shape[1] > 1 else self._decode
-        nxt, state = program(self.params, self._masked_state(),
-                             jax.numpy.asarray(tokens))
+        with phase("serve.inputs"):
+            state = self._masked_state()
+            toks = jax.numpy.asarray(tokens)
+        with phase("serve.dispatch"):
+            nxt, state = program(self.params, state, toks)
         self.cache.k_pages = state["k_pages"]
         self.cache.v_pages = state["v_pages"]
         self._c["n_total_dispatches"].inc()
-        self._apply_decode(nxt, drafts, any_draft, now)
+        with phase("serve.wait"):
+            nxt = np.asarray(nxt)
+        with phase("serve.bank"):
+            self._apply_decode(nxt, drafts, any_draft, now)
 
     def _fused_round(self, tokens, drafts, any_draft,
                      now: float) -> None:
@@ -804,16 +823,15 @@ class ServeEngine:
         survivors.  Decode results are banked before prefill
         promotions: promotion may replay, replay may preempt — an
         unapplied decode token must never be dropped."""
-        group = list(self.prefilling.items())
-        p_tokens, tables, starts, valids, metas = \
-            self._prefill_inputs(group)
-        (d_nxt, p_nxt), state = self.programs.fused(
-            self.params, self._masked_state(),
-            jax.numpy.asarray(tokens),
-            jax.numpy.asarray(p_tokens),
-            jax.numpy.asarray(tables),
-            jax.numpy.asarray(starts),
-            jax.numpy.asarray(valids))
+        with phase("serve.inputs"):
+            group = list(self.prefilling.items())
+            p_tokens, tables, starts, valids, metas = \
+                self._prefill_inputs(group)
+            args = [self._masked_state()] + [
+                jax.numpy.asarray(a)
+                for a in (tokens, p_tokens, tables, starts, valids)]
+        with phase("serve.dispatch"):
+            (d_nxt, p_nxt), state = self.programs.fused(self.params, *args)
         self.cache.k_pages = state["k_pages"]
         self.cache.v_pages = state["v_pages"]
         # one launch subsumes a prefill dispatch and a decode round:
@@ -823,11 +841,14 @@ class ServeEngine:
         self._c["n_total_dispatches"].inc()
         self._c["n_prefill_dispatches"].inc()
         self._c["n_prefill_chunks"].inc(len(metas))
-        self._apply_decode(d_nxt, drafts, any_draft, now)
-        self._finish_prefill(metas, np.asarray(p_nxt), now)
+        with phase("serve.wait"):
+            d_nxt, p_nxt = np.asarray(d_nxt), np.asarray(p_nxt)
+        with phase("serve.bank"):
+            self._apply_decode(d_nxt, drafts, any_draft, now)
+            self._finish_prefill(metas, p_nxt, now)
 
     # ------------------------------------------------------------- step
-    def step(self, now: float = float("inf")) -> bool:
+    def step(self, now: float = float("inf"), clock=None) -> bool:
         """One engine iteration: admit what fits (up to
         ``prefill_batch`` co-ingesting prompts), advance every
         prefilling request one chunk, and run one decode/verify round
@@ -840,11 +861,33 @@ class ServeEngine:
         record: dispatch kind, rows per group, page/COW/eviction
         deltas, population sizes.  The sanitized clock ``_now``
         substitutes the step index when driven offline (``now=inf``)
-        so span/timeline times stay finite."""
-        self._now = (float(now) if now != float("inf")
-                     else float(self.n_engine_steps))
-        if not self.tel:
-            return self._step(now)
+        so span/timeline times stay finite.
+
+        ``clock``, where the caller gives one, is a zero-argument
+        callable on ``now``'s clock (wall seconds); a first token's
+        ``Request.ttft`` reads it as the step banks the token, so the
+        step that made the token counts.  Without it (a step counter,
+        or offline) TTFT reads ``now``, the step's start.
+
+        Under a JAX profiler session the step writes nested host spans
+        (``telemetry.phase``) on the device trace's clock:
+        ``serve.step`` over all of it; inside, ``serve.admit``,
+        ``serve.headroom`` (page growth, copy-on-write, eviction,
+        preemption), ``serve.inputs`` (host arrays and their copies to
+        the device), ``serve.dispatch`` (the step program's call),
+        ``serve.wait`` (blocked on its tokens), ``serve.bank`` (banking,
+        promotion, prefix registration, events) and ``serve.replay``
+        (each replay dispatch)."""
+        with phase("serve.step"):
+            self._clock = clock
+            self._now = (float(now) if now != float("inf")
+                         else float(self.n_engine_steps))
+            if not self.tel:
+                return self._step(now)
+            return self._recorded_step(now)
+
+    def _recorded_step(self, now: float) -> bool:
+        """``_step`` and its step-timeline record (tracing on)."""
         pre = (self._c["n_prefill_dispatches"].value,
                self._c["n_decode_steps"].value,
                self._c["n_replay_steps"].value,
@@ -894,7 +937,8 @@ class ServeEngine:
         if self.n_inflight:
             self._c["n_engine_steps"].inc()
         while True:
-            self._admit_burst(now)
+            with phase("serve.admit"):
+                self._admit_burst(now)
             if not self.prefilling:
                 break
             if self.fused and self.active:
@@ -945,9 +989,12 @@ class ServeEngine:
         for r in requests:
             self.submit(r)
         t0 = time.perf_counter()
+
+        def clock():
+            return time.perf_counter() - t0
         while True:
-            now = (time.perf_counter() - t0) if realtime else float("inf")
-            if not self.step(now=now):
+            now = clock() if realtime else float("inf")
+            if not self.step(now=now, clock=clock if realtime else None):
                 break
             if realtime and not self.active and not self.prefilling \
                     and self.waiting:

@@ -50,6 +50,15 @@ __all__ = ["make_prefill_step", "make_decode_step",
            "ServePrograms"]
 
 
+def _sample(logits, sample: str):
+    """Token ids from the last axis of ``logits``, under the ``sample``
+    scope (greedy is the only rule)."""
+    if sample != "greedy":
+        raise ValueError(sample)
+    with jax.named_scope("sample"):
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+
 def make_prefill_step(model, max_len=None) -> Callable:
     def prefill_step(params, batch):
         return model.prefill(params, batch, max_len=max_len)
@@ -59,11 +68,7 @@ def make_prefill_step(model, max_len=None) -> Callable:
 def make_decode_step(model, sample: str = "greedy") -> Callable:
     def serve_step(params, cache, tokens):
         logits, cache = model.decode_step(params, cache, tokens)
-        if sample == "greedy":
-            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        else:
-            raise ValueError(sample)
-        return nxt[:, None], cache
+        return _sample(logits, sample)[:, None], cache
     return serve_step
 
 
@@ -72,11 +77,7 @@ def make_paged_decode_step(model, sample: str = "greedy",
     def paged_step(params, state, tokens):
         logits, state = model.decode_step_paged(params, state, tokens,
                                                 tp_axis=tp_axis)
-        if sample == "greedy":
-            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        else:
-            raise ValueError(sample)
-        return nxt[:, None], state
+        return _sample(logits, sample)[:, None], state
     return paged_step
 
 
@@ -91,11 +92,7 @@ def make_verify_step(model, sample: str = "greedy",
     def verify_step(params, state, tokens):
         logits, state = model.verify_step_paged(params, state, tokens,
                                                 tp_axis=tp_axis)
-        if sample == "greedy":
-            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        else:
-            raise ValueError(sample)
-        return nxt, state
+        return _sample(logits, sample), state
     return verify_step
 
 
@@ -113,11 +110,7 @@ def make_chunk_prefill_step(model, sample: str = "greedy",
         logits, state = model.prefill_chunk_paged(
             params, state, tokens, table_rows, starts, n_valid,
             tp_axis=tp_axis)
-        if sample == "greedy":
-            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        else:
-            raise ValueError(sample)
-        return nxt[:, None], state
+        return _sample(logits, sample)[:, None], state
     return chunk_step
 
 
@@ -137,12 +130,8 @@ def make_fused_step(model, sample: str = "greedy",
         (d_logits, p_logits), state = model.fused_step_paged(
             params, state, tokens, p_tokens, p_table_rows, p_starts,
             p_n_valid, tp_axis=tp_axis)
-        if sample == "greedy":
-            d_nxt = jnp.argmax(d_logits, axis=-1).astype(jnp.int32)
-            p_nxt = jnp.argmax(p_logits, axis=-1).astype(jnp.int32)
-        else:
-            raise ValueError(sample)
-        return (d_nxt, p_nxt[:, None]), state
+        return (_sample(d_logits, sample),
+                _sample(p_logits, sample)[:, None]), state
     return fused_step
 
 

@@ -35,6 +35,11 @@ no span/record work at all (registry counters always run; they replace
 the ``+=`` the stats dicts already paid for).  This module is
 deliberately stdlib-only so ``scripts/trace_report.py`` can load it
 without importing jax.
+
+A fourth surface is not the ``Telemetry`` object's: **phase spans**
+(:func:`phase`), the engine's host phases written into a JAX profiler
+trace on the device trace's clock.  A profiler session is their
+switch; without one a span costs well under a microsecond.
 """
 from __future__ import annotations
 
@@ -270,6 +275,20 @@ def expose_counters(*names: str):
 
 
 # --------------------------------------------------------------- spans
+_TraceAnnotation = None
+
+
+def phase(name: str):
+    """A host span named ``name`` in the JAX profiler's trace (a
+    ``jax.profiler.TraceAnnotation``), for ``with phase(...):``.  It is
+    recorded only while a profiler session runs; jax is imported on the
+    first call, so this module stays importable without it."""
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        from jax.profiler import TraceAnnotation as _TraceAnnotation
+    return _TraceAnnotation(name)
+
+
 @dataclasses.dataclass(frozen=True)
 class SpanEvent:
     """One typed lifecycle event on a request's trace."""

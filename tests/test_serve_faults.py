@@ -47,7 +47,7 @@ class _Dummy:
     def submit(self, req):
         self._inflight += 1
 
-    def step(self, now=float("inf")):
+    def step(self, now=float("inf"), clock=None):
         self.n_stepped += 1
         if self._inflight:
             self._inflight -= 1

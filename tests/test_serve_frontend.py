@@ -77,7 +77,7 @@ class FakeBackend:
         self.active[req.rid] = req
         self.dispatch_order.append(req.rid)
 
-    def step(self, now=float("inf")):
+    def step(self, now=float("inf"), clock=None):
         for rid, req in list(self.active.items()):
             req.generated.append(rid * 1000 + len(req.generated))
             done = len(req.generated) >= req.max_new_tokens
