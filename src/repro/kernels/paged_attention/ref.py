@@ -15,10 +15,20 @@ import jax
 import jax.numpy as jnp
 
 
-def gather_pages(pages, page_tables):
-    """(P, ps, KVH, Dh) + (B, n) -> (B, n * ps, KVH, Dh)."""
+def gather_pages(pages, page_tables, layer=None):
+    """(P, ps, KVH, Dh) + (B, n) -> (B, n * ps, KVH, Dh).
+
+    With ``layer`` (a traced int), ``pages`` is the stacked pool
+    (L, P, ps, KVH, Dh) and the pages of that layer are read straight
+    from it: one gather from the pool viewed as (L * P, ps, KVH, Dh) at
+    ``layer * P + page_tables``, so no layer's pool is ever sliced out
+    of the stack."""
     B, n = page_tables.shape
     with jax.named_scope("attn.gather"):
+        if layer is not None:
+            L, P = pages.shape[:2]
+            pages = pages.reshape(L * P, *pages.shape[2:])
+            page_tables = layer * P + page_tables
         g = pages[page_tables]                   # (B, n, ps, KVH, Dh)
         return g.reshape(B, n * pages.shape[1], *pages.shape[2:])
 
@@ -27,11 +37,18 @@ def paged_attention_ref(q, k_pages, v_pages, page_tables, lengths):
     """q: (B, H, Dh); k/v_pages: (P, ps, KVH, Dh); page_tables:
     (B, n) int32; lengths: (B,) attendable tokens per sequence
     (including the one just written).  Returns (B, H, Dh)."""
+    return decode_attention_core(q, gather_pages(k_pages, page_tables),
+                                 gather_pages(v_pages, page_tables),
+                                 lengths)
+
+
+def decode_attention_core(q, k, v, lengths):
+    """``paged_attention_ref`` past the gather: q (B, H, Dh) against
+    the gathered buffers k/v (B, S, KVH, Dh), positions j < lengths[b]
+    attendable.  Returns (B, H, Dh)."""
     B, H, Dh = q.shape
-    KVH = k_pages.shape[2]
+    KVH = k.shape[2]
     G = H // KVH
-    k = gather_pages(k_pages, page_tables)       # (B, S, KVH, Dh)
-    v = gather_pages(v_pages, page_tables)
     with jax.named_scope("attn.core"):
         qh = q.reshape(B, KVH, G, Dh)
         s = jnp.einsum("bkgd,bjkd->bkgj", qh, k,
@@ -62,11 +79,18 @@ def paged_verify_attention_ref(q, k_pages, v_pages, page_tables, lengths):
     q: (B, T, H, Dh); k/v_pages: (P, ps, KVH, Dh); page_tables: (B, n)
     int32; lengths: (B,) tokens resident *before* this verify call's T
     writes.  Returns (B, T, H, Dh)."""
+    return verify_attention_core(q, gather_pages(k_pages, page_tables),
+                                 gather_pages(v_pages, page_tables),
+                                 lengths)
+
+
+def verify_attention_core(q, k, v, lengths):
+    """``paged_verify_attention_ref`` past the gather: q (B, T, H, Dh)
+    against the gathered buffers k/v (B, S, KVH, Dh).  Returns
+    (B, T, H, Dh)."""
     B, T, H, Dh = q.shape
-    KVH = k_pages.shape[2]
+    KVH = k.shape[2]
     G = H // KVH
-    k = gather_pages(k_pages, page_tables)       # (B, S, KVH, Dh)
-    v = gather_pages(v_pages, page_tables)
     with jax.named_scope("attn.core"):
         qh = q.reshape(B, T, KVH, G, Dh)
         s = jnp.einsum("btkgd,bjkd->btkgj", qh, k,

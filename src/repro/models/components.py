@@ -532,19 +532,46 @@ def verify_scatter_targets(lengths, page_table, n_tokens, page_size):
     return pid.astype(jnp.int32), slot.astype(jnp.int32)
 
 
+def _gather_layer_pages(pages, table, layer, like):
+    """Layer ``layer``'s pages of ``table`` from the stacked pool (a
+    token per (KVH, Dh) slab or KVH * Dh row, see
+    DecoderLM.paged_state_specs), as a (B, n * ps, KVH, Dh) buffer with
+    the head split of ``like`` (B, T, KVH, Dh)."""
+    from ..kernels.paged_attention.ref import gather_pages
+    return gather_pages(pages, table, layer).reshape(
+        table.shape[0], -1, *like.shape[2:])
+
+
+def overlay_tokens(buf, new, lengths):
+    """Write a step's own tokens into a row's gathered context: token t
+    of row b (absolute position ``lengths[b] + t``) lands at
+    ``buf[b, lengths[b] + t]``.  ``buf``: (B, S, KVH, Dh) gathered from
+    the incoming pages; ``new``: (B, T, KVH, Dh).  A position past the
+    buffer's end is dropped, never clamped onto the row's last slot —
+    what padding the buffer by T lanes and slicing them back would
+    give, without copying the buffer.  Pure data movement: the buffer
+    holds the bits a write into the pages followed by the gather would
+    have given at every position the row owns."""
+    B, T = new.shape[:2]
+    pos = lengths[:, None] + jnp.arange(T, dtype=lengths.dtype)[None]
+    return buf.at[jnp.arange(B)[:, None], pos].set(
+        new.astype(buf.dtype), mode="drop")
+
+
 def paged_attention_block(p, x, cfg, *, positions, k_pages, v_pages,
-                          page_table, lengths, tp_axis=None):
+                          layer, page_table, lengths, tp_axis=None):
     """Paged decode attention sub-layer (continuous batching).
 
     x: (B, 1, D) with *per-request* positions (B, 1) — unlike
     ``attention_block``'s lockstep scalar ``cache_pos``, every sequence
-    in the batch sits at its own depth.  The new token's K/V is written
-    into its page slot (``page_table[b, len_b // ps]`` at offset
-    ``len_b % ps``) and attention runs over the gathered pages.
-
-    Inactive batch slots carry an all-zero page table, so their writes
-    land on the reserved null page (see serve/kv_cache.py) and never
-    corrupt live data.  Returns (out, k_pages, v_pages).
+    in the batch sits at its own depth.  ``k_pages``/``v_pages`` are the
+    stacked pools (L, P, ps, ...), read-only: attention gathers
+    layer ``layer``'s pages of ``page_table`` from them, overlays the
+    new token's K/V at position ``lengths[b]`` (``overlay_tokens``) and
+    attends over the buffer.  Returns (out, k, v) with the new token's
+    K/V (B, 1, KVH, Dh) in the page dtype: *the caller owns page
+    persistence* (DecoderLM.write_pages, which routes inactive rows'
+    all-zero tables to the reserved null page, see serve/kv_cache.py).
 
     Under tensor parallelism (``tp_axis`` set, see serve/parallel.py)
     ``cfg`` is the *local* per-shard view: q/k/v carry this shard's
@@ -552,27 +579,25 @@ def paged_attention_block(p, x, cfg, *, positions, k_pages, v_pages,
     heads are re-gathered (concatenation, never reduction) before the
     replicated output projection.
     """
-    from ..kernels.paged_attention.ref import paged_attention_ref
+    from ..kernels.paged_attention.ref import decode_attention_core
     B, S, D = x.shape
     assert S == 1, "paged path is decode-only"
     q, k, v = _project_qkv(p, x, cfg, positions)
-    ps = k_pages.shape[1]
+    k, v = k.astype(k_pages.dtype), v.astype(v_pages.dtype)
+    kb = _gather_layer_pages(k_pages, page_table, layer, k)
+    vb = _gather_layer_pages(v_pages, page_table, layer, v)
     with jax.named_scope("attn.kv_write"):
-        bidx = jnp.arange(B)
-        pidx = page_table[bidx, lengths // ps]        # (B,)
-        slot = lengths % ps
-        k_pages = k_pages.at[pidx, slot].set(k[:, 0].astype(k_pages.dtype))
-        v_pages = v_pages.at[pidx, slot].set(v[:, 0].astype(v_pages.dtype))
-    out = paged_attention_ref(q[:, 0], k_pages, v_pages, page_table,
-                              lengths + 1)
+        kb = overlay_tokens(kb, k, lengths)
+        vb = overlay_tokens(vb, v, lengths)
+    out = decode_attention_core(q[:, 0], kb, vb, lengths + 1)
     out = _tp_gather_heads(out, tp_axis, axis=1)       # (B, H, Dh)
     out = out.reshape(B, 1, -1)
     out = out @ p["wo"].astype(out.dtype)
-    return out, k_pages, v_pages
+    return out, k, v
 
 
 def paged_verify_attention_block(p, x, cfg, *, positions, k_pages,
-                                 v_pages, page_table, lengths,
+                                 v_pages, layer, page_table, lengths,
                                  tp_axis=None):
     """Speculative-verification attention sub-layer (paged decode with a
     query-time axis).
@@ -580,36 +605,37 @@ def paged_verify_attention_block(p, x, cfg, *, positions, k_pages,
     x: (B, T, D) — token 0 of row b is the request's last confirmed
     token, tokens 1..T-1 its draft continuation, token t sitting at
     absolute position ``lengths[b] + t`` (per-request positions, like
-    ``paged_attention_block``).  All T tokens' K/V are written into
-    their page slots first — the caller guarantees every written page
-    is private (copy-on-write / headroom happen host-side *before* the
-    program runs; see serve/kv_cache.ensure_headroom) or is the null
-    page for positions the row will never confirm — then attention runs
-    over the gathered pages with per-(row, t) causal masking, so query
-    t sees exactly the context the single-token decode step at its
-    position would have seen.  Verifying T = 1 tokens *is* the decode
-    step, bit for bit.
+    ``paged_attention_block``).  All T tokens' K/V are overlaid on the
+    row's gathered pages (``overlay_tokens``; positions past the
+    table's end are dropped), then attention runs with per-(row, t)
+    causal masking, so query t sees exactly the context the
+    single-token decode step at its position would have seen: every
+    position the row can confirm lies on a page it owns (copy-on-write
+    / headroom happen host-side *before* the program runs; see
+    serve/kv_cache.ensure_headroom).  Verifying T = 1 tokens *is* the
+    decode step, bit for bit.
 
-    Returns (out, k_pages, v_pages).
+    Returns (out, k, v), the T tokens' K/V (B, T, KVH, Dh) in the page
+    dtype for the caller's page write.
     """
-    from ..kernels.paged_attention.ref import paged_verify_attention_ref
+    from ..kernels.paged_attention.ref import verify_attention_core
     B, T, D = x.shape
     q, k, v = _project_qkv(p, x, cfg, positions)
-    ps = k_pages.shape[1]
+    k, v = k.astype(k_pages.dtype), v.astype(v_pages.dtype)
+    kb = _gather_layer_pages(k_pages, page_table, layer, k)
+    vb = _gather_layer_pages(v_pages, page_table, layer, v)
     with jax.named_scope("attn.kv_write"):
-        pidx, slot = verify_scatter_targets(lengths, page_table, T, ps)
-        k_pages = k_pages.at[pidx, slot].set(k.astype(k_pages.dtype))
-        v_pages = v_pages.at[pidx, slot].set(v.astype(v_pages.dtype))
-    out = paged_verify_attention_ref(q, k_pages, v_pages, page_table,
-                                     lengths)
+        kb = overlay_tokens(kb, k, lengths)
+        vb = overlay_tokens(vb, v, lengths)
+    out = verify_attention_core(q, kb, vb, lengths)
     out = _tp_gather_heads(out, tp_axis, axis=2)       # (B, T, H, Dh)
     out = out.reshape(B, T, -1)
     out = out @ p["wo"].astype(out.dtype)
-    return out, k_pages, v_pages
+    return out, k, v
 
 
 def paged_chunk_attention_block(p, x, cfg, *, positions, starts, n_valid,
-                                k_pages, v_pages, table_rows,
+                                k_pages, v_pages, layer, table_rows,
                                 tp_axis=None):
     """Batched chunked-prefill attention sub-layer over a paged KV
     cache: one chunk each for up to B co-ingesting requests.
@@ -622,7 +648,8 @@ def paged_chunk_attention_block(p, x, cfg, *, positions, starts, n_valid,
     ``table_rows``: (B, nb) int32 — each request's page table truncated
     to the dispatch's shared context bucket, covering every position
     < starts[b] + n_valid[b]; inactive rows and entries past a row's
-    own allocation carry the null page.
+    own allocation carry the null page.  ``k_pages``/``v_pages``: the
+    stacked pools, read at layer ``layer``.
 
     Per row, earlier chunks' context is gathered from pages into a
     contiguous (nb * ps) buffer and the current chunk's K/V is overlaid
@@ -638,21 +665,22 @@ def paged_chunk_attention_block(p, x, cfg, *, positions, starts, n_valid,
     to the same chunk dispatched alone, whatever else shares the batch.
     The serve engine's token-parity guarantee rests on both.
 
-    Returns (out, k, v); *the caller owns page persistence* — one
-    stacked scatter after the layer scan is far cheaper than per-layer
-    scatters here (see DecoderLM.prefill_chunk_paged).
+    Returns (out, k, v) with the chunk's K/V in the page dtype; *the
+    caller owns page persistence* — one stacked scatter over all layers
+    (DecoderLM.write_pages).
     """
     B, C, D = x.shape
     q, k, v = _project_qkv(p, x, cfg, positions)
+    k, v = k.astype(k_pages.dtype), v.astype(v_pages.dtype)
+    kc = _gather_layer_pages(k_pages, table_rows, layer, k)
+    vc = _gather_layer_pages(v_pages, table_rows, layer, v)
     with jax.named_scope("attn.gather"):
-        kc = k_pages[table_rows].reshape(B, -1, *k_pages.shape[2:])
-        vc = v_pages[table_rows].reshape(B, -1, *v_pages.shape[2:])
         kc = jnp.pad(kc, ((0, 0), (0, C), (0, 0), (0, 0)))
         vc = jnp.pad(vc, ((0, 0), (0, C), (0, 0), (0, 0)))
         overlay = jax.vmap(lambda buf, new, s:
                            lax.dynamic_update_slice(buf, new, (s, 0, 0)))
-        kc = overlay(kc, k.astype(kc.dtype), starts)
-        vc = overlay(vc, v.astype(vc.dtype), starts)
+        kc = overlay(kc, k, starts)
+        vc = overlay(vc, v, starts)
     with jax.named_scope("attn.core"):
         out = flash_attention(q, kc, vc, causal=True,
                               kv_chunk=cfg.attn_kv_chunk, q_offset=starts)

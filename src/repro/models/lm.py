@@ -394,11 +394,26 @@ class DecoderLM:
                 and self.cfg.rope_kind != "mrope")
 
     def paged_state_specs(self, batch: int, *, n_pages: int,
-                          page_size: int, max_pages_per_seq: int) -> dict:
+                          page_size: int, max_pages_per_seq: int,
+                          shards: int = 1) -> dict:
+        """The paged serve state; ``shards`` devices split the pools'
+        head axis (tensor-parallel serving, serve/parallel.py)."""
         cfg = self.cfg
-        shp = (cfg.n_layers, n_pages, page_size, cfg.n_kv_heads,
-               cfg.head_dim)
-        ax = ("layers", None, "kv_seq", "act_heads", None)
+        kvh, dh = cfg.n_kv_heads, cfg.head_dim
+        # a token's K (or V) in its page: the (KVH, Dh) slab where one
+        # shard's slab (KVH / shards, Dh) fills whole (8, 128) memory
+        # tiles of the chip, else one row of KVH * Dh (heads major).  A
+        # slab that pads its tiles makes the chip's default layout put
+        # the page axis minor, which no page gather or scatter can read
+        # in place; a row that the attention must split into heads
+        # costs a relayout of every gathered buffer.  Either way each
+        # shard's pool keeps the row-major layout that the step programs
+        # gather from and the writer scatters into.
+        tiled = (kvh // shards) % 8 == 0 and dh % 128 == 0
+        shp = (cfg.n_layers, n_pages, page_size) + (
+            (kvh, dh) if tiled else (kvh * dh,))
+        ax = ("layers", None, "kv_seq", "act_heads") + (
+            (None,) if tiled else ())
         return {
             "k_pages": ParamSpec(shp, ax, jnp.bfloat16),
             "v_pages": ParamSpec(shp, ax, jnp.bfloat16),
@@ -411,11 +426,24 @@ class DecoderLM:
     # (a device op's scope path in the profiler trace, e.g.
     # ``jit(fused_step)/while/body/attn/attn.core/...``): ``embed``;
     # per layer ``norm``, ``attn`` (children ``attn.gather``,
-    # ``attn.core``, ``attn.kv_write``) and ``mlp``; after the layer
-    # scan ``kv_scatter``, ``norm`` and ``unembed``; ``sample`` in
-    # serve/step.py.  The scan's own slicing of its inputs (the stacked
-    # weights and page pools) and stacking of its outputs stay outside
-    # every scope, so the layer loop's unscoped ops are that traffic.
+    # ``attn.core``, ``attn.kv_write``: the step's own K/V overlaid on
+    # the gathered pages) and ``mlp``; after the layer scan
+    # ``kv_scatter`` (the write targets), ``norm`` and ``unembed``;
+    # ``sample`` in serve/step.py; ``kv_scatter`` again in the page
+    # writer.  The scan's own slicing of its inputs (the stacked weights
+    # and the layer index) and stacking of its outputs (the new K/V)
+    # stay outside every scope, so the layer loop's unscoped ops are
+    # that traffic.
+    #
+    # Write order: each program is "compute, then write".  The compute
+    # half (``decode_paged``, ``verify_paged``, ``chunk_paged``,
+    # ``fused_paged``) reads the incoming pools, closed over and
+    # read-only, and returns the step's new K/V with their (page, slot)
+    # targets; ``write_pages`` persists them, one stacked scatter over
+    # all layers per row group.  The serve engine runs the two as
+    # separate programs so that the writer alone takes the pools
+    # donated (serve/step.ServePrograms.write_pages); the
+    # ``*_step_paged`` methods compose them in one.
     def _paged_layer(self, lp, x, attend, tp_axis):
         """One layer of a paged step program on the residual stream
         ``x``: ``attend(h)`` is the attention sub-layer on the normed
@@ -449,18 +477,97 @@ class DecoderLM:
                     x, jnp.maximum(n_valid - 1, 0)[:, None, None], axis=1)
             return C.unembed(params["embed"], x, cfg)
 
+    def _paged_scan(self, body, carry, params):
+        """The layer loop of a paged step program: ``body(carry, (lp,
+        layer))`` over the stacked weights and the layer index only;
+        the page pools stay closed over, read-only, so the loop never
+        slices, restacks or copies a pool."""
+        layers = jnp.arange(self.cfg.n_layers, dtype=jnp.int32)
+        return lax.scan(body, carry, (params["layers"], layers))
+
     @staticmethod
-    def _kv_scatter(k_pages, v_pages, ks, vs, starts, n_valid, table_rows):
-        """Persist every prefill row's chunk K/V (``ks``/``vs``: (L, B,
-        C, KVH, Dh), stacked by the layer scan) for every layer in one
-        stacked scatter.  Tokens t >= n_valid[b] are routed to null page
-        0; write-target pages are private per row (COW at admission), so
-        co-ingested rows never scatter into each other."""
+    def write_pages(k_pages, v_pages, writes):
+        """Persist a step's new K/V: ``writes`` is a tuple of row groups
+        ``{"k", "v": (L, B, T, KVH, Dh); "page", "slot": (B, T)}`` (as
+        the compute halves return them), each written for every layer
+        in one stacked scatter, in order.  The scatter stores whole
+        tokens into the pool viewed as (L * P * ps, <token>), at
+        ``(layer * P + page) * ps + slot`` — the view merges only the
+        pool's leading axes, so it is the pool's own layout and nothing
+        is copied.  Targets past a row's table or valid tokens are the
+        null page (``chunk_scatter_targets`` /
+        ``verify_scatter_targets``); the groups' other targets are
+        disjoint (see ``fused_paged``), so the order shows only on the
+        null page.  Returns (k_pages, v_pages)."""
+        L, P, ps = k_pages.shape[:3]
+
+        def store(pages, new, rows):
+            token = pages.shape[3:]
+            flat = pages.reshape(L * P * ps, *token)
+            flat = flat.at[rows].set(new.reshape(rows.size, *token))
+            return flat.reshape(pages.shape)
+
         with jax.named_scope("kv_scatter"):
-            pid, slot = C.chunk_scatter_targets(
-                starts, n_valid, table_rows, ks.shape[2], k_pages.shape[2])
-            return (k_pages.at[:, pid, slot].set(ks.astype(k_pages.dtype)),
-                    v_pages.at[:, pid, slot].set(vs.astype(v_pages.dtype)))
+            layer = jnp.arange(L, dtype=jnp.int32)[:, None, None]
+            for w in writes:
+                rows = ((layer * P + w["page"]) * ps + w["slot"]).ravel()
+                k_pages = store(k_pages, w["k"], rows)
+                v_pages = store(v_pages, w["v"], rows)
+        return k_pages, v_pages
+
+    @staticmethod
+    def _decode_group(ks, vs, state, T):
+        """The decode/verify rows' writes: token t of row b at position
+        ``lengths[b] + t`` (``verify_scatter_targets``)."""
+        with jax.named_scope("kv_scatter"):
+            page, slot = C.verify_scatter_targets(
+                state["lengths"], state["page_tables"], T,
+                state["k_pages"].shape[2])
+        return {"k": ks, "v": vs, "page": page, "slot": slot}
+
+    @staticmethod
+    def _chunk_group(ks, vs, state, starts, n_valid, table_rows):
+        """The prefill rows' writes (``chunk_scatter_targets``): write
+        targets are private per row (COW at admission), so co-ingested
+        rows never write into each other."""
+        with jax.named_scope("kv_scatter"):
+            page, slot = C.chunk_scatter_targets(
+                starts, n_valid, table_rows, ks.shape[2],
+                state["k_pages"].shape[2])
+        return {"k": ks, "v": vs, "page": page, "slot": slot}
+
+    def _written(self, state, writes, **rest):
+        k_pages, v_pages = self.write_pages(state["k_pages"],
+                                            state["v_pages"], writes)
+        return {"k_pages": k_pages, "v_pages": v_pages, **rest}
+
+    def chunk_paged(self, params, state, tokens, table_rows, starts,
+                    n_valid, tp_axis=None):
+        """The compute half of ``prefill_chunk_paged``: (per-row
+        last-valid-token logits (B, V), writes)."""
+        assert self.supports_paged_decode()
+        cfg = self.cfg
+        assert not (tp_axis is not None and cfg.moe is not None)
+        dtype = jnp.dtype(cfg.compute_dtype)
+        n = tokens.shape[1]
+        positions = starts[:, None] + jnp.arange(n, dtype=jnp.int32)[None]
+        x = self._embed_inputs(
+            params, {"tokens": tokens, "positions": positions}, dtype)
+
+        def body(x, inp):
+            lp, layer = inp
+            return self._paged_layer(
+                lp, x, lambda h: C.paged_chunk_attention_block(
+                    lp["mix"], h, cfg, positions=positions, starts=starts,
+                    n_valid=n_valid, k_pages=state["k_pages"],
+                    v_pages=state["v_pages"], layer=layer,
+                    table_rows=table_rows, tp_axis=tp_axis), tp_axis)
+
+        x, (ks, vs) = self._paged_scan(body, x, params)
+        writes = (self._chunk_group(ks, vs, state, starts, n_valid,
+                                    table_rows),)
+        logits = self._paged_logits(params, x, n_valid)
+        return logits[:, 0], writes
 
     def prefill_chunk_paged(self, params, state, tokens, table_rows,
                             starts, n_valid, tp_axis=None):
@@ -478,7 +585,7 @@ class DecoderLM:
         bucket — which requests co-ingest can never change numerics.
         Returns (per-row last-valid-token logits (B, V), new page
         state); a row's logits are only meaningful on the chunk that
-        completes its prompt.
+        completes its prompt.  ``chunk_paged`` then ``write_pages``.
 
         Token-exactness: the flash partition is anchored at absolute
         position 0, the K/V gathered back from pages carry the same
@@ -492,45 +599,14 @@ class DecoderLM:
         of a tensor-parallel ``shard_map`` program (serve/parallel.py;
         ``self`` is then the shard-local model view).
         """
-        assert self.supports_paged_decode()
-        cfg = self.cfg
-        assert not (tp_axis is not None and cfg.moe is not None)
-        dtype = jnp.dtype(cfg.compute_dtype)
-        n = tokens.shape[1]
-        positions = starts[:, None] + jnp.arange(n, dtype=jnp.int32)[None]
-        x = self._embed_inputs(
-            params, {"tokens": tokens, "positions": positions}, dtype)
+        logits, writes = self.chunk_paged(params, state, tokens,
+                                          table_rows, starts, n_valid,
+                                          tp_axis=tp_axis)
+        return logits, self._written(state, writes)
 
-        def body(x, inp):
-            lp, kp, vp = inp
-            return self._paged_layer(
-                lp, x, lambda h: C.paged_chunk_attention_block(
-                    lp["mix"], h, cfg, positions=positions, starts=starts,
-                    n_valid=n_valid, k_pages=kp, v_pages=vp,
-                    table_rows=table_rows, tp_axis=tp_axis), tp_axis)
-
-        x, (ks, vs) = lax.scan(
-            body, x, (params["layers"], state["k_pages"],
-                      state["v_pages"]))
-        k_pages, v_pages = self._kv_scatter(
-            state["k_pages"], state["v_pages"], ks, vs, starts, n_valid,
-            table_rows)
-        logits = self._paged_logits(params, x, n_valid)
-        return logits[:, 0], {"k_pages": k_pages, "v_pages": v_pages}
-
-    def decode_step_paged(self, params, state, tokens, tp_axis=None):
-        """One continuous-batching decode step against a paged KV cache.
-
-        ``state``: {k_pages, v_pages: (L, P, ps, KVH, Dh); page_tables:
-        (B, n) int32; lengths: (B,) int32}.  ``tokens``: (B, 1).  Each
-        sequence decodes at its own position ``lengths[b]`` (no
-        lockstep).  Returns (logits (B, V), new state) with lengths
-        advanced; callers that mask inactive slots (the serve engine)
-        own the authoritative lengths host-side.
-
-        ``tp_axis``: mesh axis name when running as the per-shard body
-        of a tensor-parallel ``shard_map`` program (serve/parallel.py).
-        """
+    def decode_paged(self, params, state, tokens, tp_axis=None):
+        """The compute half of ``decode_step_paged``: (logits (B, V),
+        writes)."""
         assert self.supports_paged_decode()
         cfg = self.cfg
         assert not (tp_axis is not None and cfg.moe is not None)
@@ -542,20 +618,66 @@ class DecoderLM:
             params, {"tokens": tokens, "positions": positions}, dtype)
 
         def body(x, inp):
-            lp, kp, vp = inp
+            lp, layer = inp
             return self._paged_layer(
                 lp, x, lambda h: C.paged_attention_block(
-                    lp["mix"], h, cfg, positions=positions, k_pages=kp,
-                    v_pages=vp, page_table=tables, lengths=lengths,
+                    lp["mix"], h, cfg, positions=positions,
+                    k_pages=state["k_pages"], v_pages=state["v_pages"],
+                    layer=layer, page_table=tables, lengths=lengths,
                     tp_axis=tp_axis), tp_axis)
 
-        x, (k_pages, v_pages) = lax.scan(
-            body, x, (params["layers"], state["k_pages"],
-                      state["v_pages"]))
+        x, (ks, vs) = self._paged_scan(body, x, params)
+        writes = (self._decode_group(ks, vs, state, 1),)
         logits = self._paged_logits(params, x)
-        return logits[:, 0], {"k_pages": k_pages, "v_pages": v_pages,
-                              "page_tables": tables,
-                              "lengths": lengths + 1}
+        return logits[:, 0], writes
+
+    def decode_step_paged(self, params, state, tokens, tp_axis=None):
+        """One continuous-batching decode step against a paged KV cache.
+
+        ``state``: {k_pages, v_pages: (L, P, ps, KVH, Dh); page_tables:
+        (B, n) int32; lengths: (B,) int32}.  ``tokens``: (B, 1).  Each
+        sequence decodes at its own position ``lengths[b]`` (no
+        lockstep).  Returns (logits (B, V), new state) with lengths
+        advanced; callers that mask inactive slots (the serve engine)
+        own the authoritative lengths host-side.  ``decode_paged``
+        then ``write_pages``.
+
+        ``tp_axis``: mesh axis name when running as the per-shard body
+        of a tensor-parallel ``shard_map`` program (serve/parallel.py).
+        """
+        logits, writes = self.decode_paged(params, state, tokens,
+                                           tp_axis=tp_axis)
+        return logits, self._written(state, writes,
+                                     page_tables=state["page_tables"],
+                                     lengths=state["lengths"] + 1)
+
+    def verify_paged(self, params, state, tokens, tp_axis=None):
+        """The compute half of ``verify_step_paged``: (logits (B, T, V),
+        writes)."""
+        assert self.supports_paged_decode()
+        cfg = self.cfg
+        assert not (tp_axis is not None and cfg.moe is not None)
+        dtype = jnp.dtype(cfg.compute_dtype)
+        lengths = state["lengths"]
+        tables = state["page_tables"]
+        B, T = tokens.shape
+        positions = (lengths[:, None]
+                     + jnp.arange(T, dtype=jnp.int32)[None, :])
+        x = self._embed_inputs(
+            params, {"tokens": tokens, "positions": positions}, dtype)
+
+        def body(x, inp):
+            lp, layer = inp
+            return self._paged_layer(
+                lp, x, lambda h: C.paged_verify_attention_block(
+                    lp["mix"], h, cfg, positions=positions,
+                    k_pages=state["k_pages"], v_pages=state["v_pages"],
+                    layer=layer, page_table=tables, lengths=lengths,
+                    tp_axis=tp_axis), tp_axis)
+
+        x, (ks, vs) = self._paged_scan(body, x, params)
+        writes = (self._decode_group(ks, vs, state, T),)
+        return self._paged_logits(params, x), writes
 
     def verify_step_paged(self, params, state, tokens, tp_axis=None):
         """Score T tokens per request in one batched pass against the
@@ -570,7 +692,7 @@ class DecoderLM:
         sequentially consuming tokens 0..t (same per-token projections,
         same gathered-buffer softmax shape — docs/speculative.md spells
         out the argument).  T = 1 degenerates to exactly one decode
-        step.
+        step.  ``verify_paged`` then ``write_pages``.
 
         Returns (logits (B, T, V), new state).  ``lengths`` is returned
         *unadvanced*: how many of the T positions become real history
@@ -579,66 +701,16 @@ class DecoderLM:
         hold stale page writes that masking hides, like any slot past
         ``lengths``.
         """
-        assert self.supports_paged_decode()
-        cfg = self.cfg
-        assert not (tp_axis is not None and cfg.moe is not None)
-        dtype = jnp.dtype(cfg.compute_dtype)
-        lengths = state["lengths"]
-        tables = state["page_tables"]
-        B, T = tokens.shape
-        positions = (lengths[:, None]
-                     + jnp.arange(T, dtype=jnp.int32)[None, :])
-        x = self._embed_inputs(
-            params, {"tokens": tokens, "positions": positions}, dtype)
+        logits, writes = self.verify_paged(params, state, tokens,
+                                           tp_axis=tp_axis)
+        return logits, self._written(state, writes,
+                                     page_tables=state["page_tables"],
+                                     lengths=state["lengths"])
 
-        def body(x, inp):
-            lp, kp, vp = inp
-            return self._paged_layer(
-                lp, x, lambda h: C.paged_verify_attention_block(
-                    lp["mix"], h, cfg, positions=positions, k_pages=kp,
-                    v_pages=vp, page_table=tables, lengths=lengths,
-                    tp_axis=tp_axis), tp_axis)
-
-        x, (k_pages, v_pages) = lax.scan(
-            body, x, (params["layers"], state["k_pages"],
-                      state["v_pages"]))
-        logits = self._paged_logits(params, x)
-        return logits, {"k_pages": k_pages, "v_pages": v_pages,
-                        "page_tables": tables, "lengths": lengths}
-
-    def fused_step_paged(self, params, state, d_tokens, p_tokens,
-                         p_table_rows, p_starts, p_n_valid,
-                         tp_axis=None):
-        """One fused engine step: decode/verify every DECODING slot AND
-        ingest one prompt chunk for every PREFILLING request in a
-        single program dispatch (the steady-state step of the serve
-        engine collapses from two launches to one).
-
-        ``d_tokens``: (B, T) — the decode/verify rows, exactly as
-        ``decode_step_paged`` (T == 1) / ``verify_step_paged`` (T > 1)
-        would receive them, positioned by ``state["lengths"]``.
-        ``p_tokens`` / ``p_table_rows`` / ``p_starts`` / ``p_n_valid``:
-        the chunked-prefill rows, exactly as ``prefill_chunk_paged``
-        would receive them (inactive rows null-routed).  Returns
-        ``((d_logits (B, T, V), p_logits (Bp, V)), new state)`` with
-        ``lengths`` unadvanced (the host owns authoritative lengths).
-
-        Token-exactness vs the two sequential dispatches rests on page
-        **write/read disjointness**: prefill rows scatter only into
-        their own private pages (copy-on-write at admission; shared
-        trie pages are read-only), decode rows write only into pages
-        ``ensure_headroom`` privatized for them, decode gathers only
-        active-slot tables (which never contain a prefill row's private
-        pages) and prefill gathers only its own table prefix (which
-        never contains a decode write target).  Both groups therefore
-        read the *incoming* pages — exactly what each would see
-        dispatched separately in either order — and their page scatters
-        land on disjoint (page, slot) targets, so the combined update
-        commutes.  Inside one step every other op is row-independent
-        (components.paged_chunk_attention_block /
-        paged_verify_attention_block), so each row is bit-identical to
-        its unfused counterpart.
-        """
+    def fused_paged(self, params, state, d_tokens, p_tokens, p_table_rows,
+                    p_starts, p_n_valid, tp_axis=None):
+        """The compute half of ``fused_step_paged``: ((d_logits (B, T,
+        V), p_logits (Bp, V)), writes), the decode rows' group first."""
         assert self.supports_paged_decode()
         cfg = self.cfg
         assert not (tp_axis is not None and cfg.moe is not None)
@@ -657,40 +729,76 @@ class DecoderLM:
         xp = self._embed_inputs(
             params, {"tokens": p_tokens, "positions": p_positions},
             dtype)
+        pools = {"k_pages": state["k_pages"], "v_pages": state["v_pages"]}
 
         def body(carry, inp):
             xd, xp = carry
-            lp, kp, vp = inp
-            # prefill attention first, reading the incoming pages
-            # (decode's writes are not in any prefill table — see
-            # disjointness above — so the order is unobservable);
-            # its K/V persists in one stacked scatter after the scan
-            xp, (k, v) = self._paged_layer(
+            lp, layer = inp
+            # both groups read the incoming pages (disjointness, above)
+            xp, (pk, pv) = self._paged_layer(
                 lp, xp, lambda h: C.paged_chunk_attention_block(
                     lp["mix"], h, cfg, positions=p_positions,
-                    starts=p_starts, n_valid=p_n_valid, k_pages=kp,
-                    v_pages=vp, table_rows=p_table_rows,
-                    tp_axis=tp_axis), tp_axis)
+                    starts=p_starts, n_valid=p_n_valid, layer=layer,
+                    table_rows=p_table_rows, tp_axis=tp_axis, **pools),
+                tp_axis)
             # decode/verify rows: T == 1 verification IS the decode
             # step bit for bit (paged_verify_attention_block), so one
             # body serves both widths
-            xd, (kp, vp) = self._paged_layer(
+            xd, (dk, dv) = self._paged_layer(
                 lp, xd, lambda h: C.paged_verify_attention_block(
-                    lp["mix"], h, cfg, positions=d_positions, k_pages=kp,
-                    v_pages=vp, page_table=tables, lengths=lengths,
-                    tp_axis=tp_axis), tp_axis)
-            return (xd, xp), (kp, vp, k, v)
+                    lp["mix"], h, cfg, positions=d_positions, layer=layer,
+                    page_table=tables, lengths=lengths, tp_axis=tp_axis,
+                    **pools), tp_axis)
+            return (xd, xp), (dk, dv, pk, pv)
 
-        (xd, xp), (k_pages, v_pages, ks, vs) = lax.scan(
-            body, (xd, xp), (params["layers"], state["k_pages"],
-                             state["v_pages"]))
-        # persist the prefill chunks' K/V into the decode-updated pages
-        # (disjoint write targets, so this commutes with the decode
-        # writes already applied in the scan)
-        k_pages, v_pages = self._kv_scatter(
-            k_pages, v_pages, ks, vs, p_starts, p_n_valid, p_table_rows)
+        (xd, xp), (dks, dvs, pks, pvs) = self._paged_scan(
+            body, (xd, xp), params)
+        writes = (self._decode_group(dks, dvs, state, T),
+                  self._chunk_group(pks, pvs, state, p_starts, p_n_valid,
+                                    p_table_rows))
         d_logits = self._paged_logits(params, xd)
         p_logits = self._paged_logits(params, xp, p_n_valid)[:, 0]
-        return (d_logits, p_logits), {
-            "k_pages": k_pages, "v_pages": v_pages,
-            "page_tables": tables, "lengths": lengths}
+        return (d_logits, p_logits), writes
+
+    def fused_step_paged(self, params, state, d_tokens, p_tokens,
+                         p_table_rows, p_starts, p_n_valid,
+                         tp_axis=None):
+        """One fused engine step: decode/verify every DECODING slot AND
+        ingest one prompt chunk for every PREFILLING request in a
+        single program dispatch (the steady-state step of the serve
+        engine collapses from two launches to one).
+
+        ``d_tokens``: (B, T) — the decode/verify rows, exactly as
+        ``decode_step_paged`` (T == 1) / ``verify_step_paged`` (T > 1)
+        would receive them, positioned by ``state["lengths"]``.
+        ``p_tokens`` / ``p_table_rows`` / ``p_starts`` / ``p_n_valid``:
+        the chunked-prefill rows, exactly as ``prefill_chunk_paged``
+        would receive them (inactive rows null-routed).  Returns
+        ``((d_logits (B, T, V), p_logits (Bp, V)), new state)`` with
+        ``lengths`` unadvanced (the host owns authoritative lengths).
+
+        Write order: compute reads the incoming pools; one writer
+        persists both halves' disjoint targets (``fused_paged`` then
+        ``write_pages``).  Token-exactness vs the two sequential
+        dispatches rests on page **write/read disjointness**: prefill
+        rows write only into their own private pages (copy-on-write at
+        admission; shared trie pages are read-only), decode rows write
+        only into pages ``ensure_headroom`` privatized for them, decode
+        gathers only active-slot tables (which never contain a prefill
+        row's private pages) and prefill gathers only its own table
+        prefix (which never contains a decode write target).  Both
+        groups therefore read the *incoming* pages — exactly what each
+        would see dispatched separately in either order — each with
+        its own new tokens overlaid, and their writes land on disjoint
+        (page, slot) targets, so the combined update commutes.  Inside
+        one step every other op is row-independent
+        (components.paged_chunk_attention_block /
+        paged_verify_attention_block), so each row is bit-identical to
+        its unfused counterpart.
+        """
+        logits, writes = self.fused_paged(
+            params, state, d_tokens, p_tokens, p_table_rows, p_starts,
+            p_n_valid, tp_axis=tp_axis)
+        return logits, self._written(state, writes,
+                                     page_tables=state["page_tables"],
+                                     lengths=state["lengths"])

@@ -11,15 +11,23 @@ so N requests carrying one system prompt pay its KV cost once.
 Device layout (for a scanned all-attention stack of L layers):
 
     k_pages, v_pages : (L, n_pages, page_size, KVH, Dh)   bf16
+                       or (L, n_pages, page_size, KVH * Dh)
     page_tables      : (max_batch, max_pages_per_seq)     int32
     lengths          : (max_batch,)                       int32
 
+A token's K (or V) is a (KVH, Dh) slab of its page where one device's
+share of it (under tensor parallelism, its own heads) fills whole
+memory tiles of the chip, else one KVH * Dh row (heads major), so each
+device's pool keeps a row-major layout whatever the head count and size
+(models/lm.paged_state_specs).
+
 Under tensor-parallel serving (serve/parallel.py) the page arrays are
-sharded on the KVH axis — each device holds every page's slice of its
-own KV heads — while page tables, lengths, and ALL of this module's
-host-side bookkeeping stay replicated/device-agnostic: a page id means
-"the same page on every shard", so allocation, refcounts, COW, the
-prefix trie, and speculation rollback are untouched by the mesh.
+sharded on the head (or row) axis, a whole number of heads per device
+— each device holds every page's slice of its own KV heads — while
+page tables, lengths, and ALL of this module's host-side bookkeeping
+stay replicated/device-agnostic: a page id means "the same page on
+every shard", so allocation, refcounts, COW, the prefix trie, and
+speculation rollback are untouched by the mesh.
 
 Invariants the engine relies on (exercised by check_invariants and
 tests/test_serve_engine.py):
@@ -89,7 +97,8 @@ def pages_needed(n_tokens: int, page_size: int) -> int:
 class PagedKVCache:
     def __init__(self, model, *, max_batch: int, n_pages: int,
                  page_size: int, max_pages_per_seq: int,
-                 prefix_sharing: bool = True, sharding=None):
+                 prefix_sharing: bool = True, sharding=None,
+                 shards: int = 1):
         cfg = model.cfg
         if not (model.scanned and model.first_dense == 0
                 and set(cfg.layer_kinds) == {"attn"}):
@@ -105,10 +114,13 @@ class PagedKVCache:
         self.max_pages_per_seq = max_pages_per_seq
 
         # allocated where the programs read it (``sharding``: a chip
-        # or the TP mesh layout; None: the default device) — never
-        # staged on the default device first
-        L = cfg.n_layers
-        shape = (L, n_pages, page_size, cfg.n_kv_heads, cfg.head_dim)
+        # or the TP mesh layout, its head axis split ``shards`` ways;
+        # None: the default device) — never staged on the default
+        # device first
+        shape = model.paged_state_specs(
+            max_batch, n_pages=n_pages, page_size=page_size,
+            max_pages_per_seq=max_pages_per_seq,
+            shards=shards)["k_pages"].shape
         self.k_pages = jnp.zeros(shape, jnp.bfloat16, device=sharding)
         self.v_pages = jnp.zeros(shape, jnp.bfloat16, device=sharding)
 

@@ -10,7 +10,7 @@ serve stack's token-parity guarantee survives the mesh:
 * **What is sharded.**  Attention heads: wq/wk/wv (and their biases)
   are split on the head output dim, so shard i computes heads
   ``[i*H/tp, (i+1)*H/tp)`` — and the paged KV cache splits the same
-  way, ``k_pages/v_pages: (L, P, ps, KVH/tp, Dh)`` per device, which
+  way, ``k_pages/v_pages: (L, P, ps, KVH/tp[, Dh])`` per device, which
   is the memory-system scaling that motivates TP serving in the first
   place (a single chip's HBM bounds resident KV; tp chips bound tp×).
   The FFN hidden dim (wg/wu, gelu w1/b1) splits identically.
@@ -64,9 +64,14 @@ from .step import (make_chunk_prefill_step, make_fused_step,
 __all__ = ["TPServePrograms", "make_tp_mesh", "validate_tp",
            "tp_param_specs", "PAGE_SPEC"]
 
-#: k_pages/v_pages (L, n_pages, page_size, KVH, Dh): sharded on the
-#: KV-head axis, the serving analogue of the training rules' act_heads.
+#: k_pages/v_pages (L, n_pages, page_size, KVH[, Dh]): sharded on the
+#: head (or heads-major row) axis, so each device holds whole KV heads
+#: — the serving analogue of the training rules' act_heads.
 PAGE_SPEC = P(None, None, None, SERVE_TP_AXIS)
+
+#: one row group of a step's writes (models/lm.write_pages): K/V
+#: (L, B, T, KVH, Dh) split on KVH like the pages, targets replicated
+_WRITE_SPECS = {"k": PAGE_SPEC, "v": PAGE_SPEC, "page": P(), "slot": P()}
 
 
 def make_tp_mesh(tp: int):
@@ -122,10 +127,16 @@ def _local_model(model, tp: int):
 
 class TPServePrograms:
     """Sharded counterpart of step.ServePrograms: same attribute
-    surface (decode / chunk / verify callables with identical
-    signatures, prepare_params / page_sharding hooks), so ServeEngine
-    uses either interchangeably — and N router replicas can share one
-    instance to share one compile cache."""
+    surface (decode / chunk / verify / fused callables with identical
+    signatures, the page writer, prepare_params / page_sharding hooks),
+    so ServeEngine uses either interchangeably — and N router replicas
+    can share one instance to share one compile cache.
+
+    A step's writes come back as they were computed: their K/V
+    (L, B, T, KVH, Dh) split on KVH like the pools (``PAGE_SPEC``
+    covers both), their targets replicated.  ``write_pages`` takes the
+    pools donated on their ``NamedSharding`` and scatters shard-locally
+    (targets index pages and slots, never the head axis)."""
 
     def __init__(self, model, *, tp: Optional[int] = None, mesh=None):
         if mesh is None:
@@ -142,53 +153,51 @@ class TPServePrograms:
         self.model = model
         self._local = _local_model(model, self.tp)
         self._pspecs = tp_param_specs(model)
-        full_state = {"k_pages": PAGE_SPEC, "v_pages": PAGE_SPEC,
-                      "page_tables": P(), "lengths": P()}
         kv_state = {"k_pages": PAGE_SPEC, "v_pages": PAGE_SPEC}
-        self.decode = jax.jit(jax.shard_map(
-            make_paged_decode_step(self._local, tp_axis=SERVE_TP_AXIS),
-            mesh=mesh, in_specs=(self._pspecs, full_state, P()),
-            out_specs=(P(), full_state), check_vma=False))
+        self.decode = self._program(make_paged_decode_step, P())
         # batched chunked prefill: (tokens, table_rows, starts,
         # n_valid) are per-row control metadata — replicated, like the
         # decode program's page tables; the heads of the gathered
-        # context and the page scatter shard with kv_state
+        # context and of the writes shard with kv_state
         self.chunk = jax.jit(jax.shard_map(
             make_chunk_prefill_step(self._local, tp_axis=SERVE_TP_AXIS),
             mesh=mesh,
             in_specs=(self._pspecs, kv_state, P(), P(), P(), P()),
-            out_specs=(P(), kv_state), check_vma=False))
+            out_specs=(P(), (_WRITE_SPECS,)), check_vma=False))
+        pages = NamedSharding(mesh, PAGE_SPEC)
+        self.write_pages = jax.jit(model.write_pages, donate_argnums=(0, 1),
+                                   out_shardings=(pages, pages))
         self._verify = None
         self._fused = None
         self._params_cache: Dict[int, object] = {}
 
+    def _program(self, make, tokens_spec, extra_in=(), n_groups=1):
+        """A decode-side program shard_map'd over the mesh: the full
+        masked state, the (replicated) tokens and ``extra_in`` in;
+        ``tokens_spec`` and ``n_groups`` write groups out."""
+        full_state = {"k_pages": PAGE_SPEC, "v_pages": PAGE_SPEC,
+                      "page_tables": P(), "lengths": P()}
+        return jax.jit(jax.shard_map(
+            make(self._local, tp_axis=SERVE_TP_AXIS), mesh=self.mesh,
+            in_specs=(self._pspecs, full_state, P()) + extra_in,
+            out_specs=(tokens_spec, (_WRITE_SPECS,) * n_groups),
+            check_vma=False))
+
     @property
     def verify(self):
         if self._verify is None:
-            full_state = {"k_pages": PAGE_SPEC, "v_pages": PAGE_SPEC,
-                          "page_tables": P(), "lengths": P()}
-            self._verify = jax.jit(jax.shard_map(
-                make_verify_step(self._local, tp_axis=SERVE_TP_AXIS),
-                mesh=self.mesh,
-                in_specs=(self._pspecs, full_state, P()),
-                out_specs=(P(), full_state), check_vma=False))
+            self._verify = self._program(make_verify_step, P())
         return self._verify
 
     @property
     def fused(self):
         # the fused step's decode half takes the full masked state, its
         # prefill half the same replicated control metadata as chunk;
-        # outputs are (replicated tokens, sharded page state) — so the
+        # outputs are (replicated tokens, both halves' writes) — so the
         # specs are exactly the union of decode's and chunk's
         if self._fused is None:
-            full_state = {"k_pages": PAGE_SPEC, "v_pages": PAGE_SPEC,
-                          "page_tables": P(), "lengths": P()}
-            self._fused = jax.jit(jax.shard_map(
-                make_fused_step(self._local, tp_axis=SERVE_TP_AXIS),
-                mesh=self.mesh,
-                in_specs=(self._pspecs, full_state, P(), P(), P(), P(),
-                          P()),
-                out_specs=((P(), P()), full_state), check_vma=False))
+            self._fused = self._program(make_fused_step, (P(), P()),
+                                        extra_in=(P(),) * 4, n_groups=2)
         return self._fused
 
     def prepare_params(self, params, device=None):
@@ -208,3 +217,7 @@ class TPServePrograms:
 
     def page_sharding(self, device=None):
         return NamedSharding(self.mesh, PAGE_SPEC)
+
+    def index_sharding(self, device=None):
+        """Where a step's write targets live: replicated."""
+        return NamedSharding(self.mesh, P())

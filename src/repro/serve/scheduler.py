@@ -137,8 +137,8 @@ class Request:
 _ENGINE_COUNTERS = (
     "n_engine_steps", "n_decode_steps", "n_prefill_chunks",
     "n_prefill_dispatches", "n_replay_steps", "n_fused_dispatches",
-    "n_total_dispatches", "n_spec_rounds", "n_drafted",
-    "n_draft_accepted")
+    "n_total_dispatches", "n_page_writes", "n_spec_rounds",
+    "n_drafted", "n_draft_accepted")
 
 
 @expose_counters(*_ENGINE_COUNTERS)
@@ -191,7 +191,8 @@ class ServeEngine:
                                   n_pages=n_pages, page_size=page_size,
                                   max_pages_per_seq=max_pages_per_seq,
                                   prefix_sharing=prefix_sharing,
-                                  sharding=programs.page_sharding(device))
+                                  sharding=programs.page_sharding(device),
+                                  shards=programs.tp)
         self.max_batch = max_batch
         self.chunk_size = chunk_size
         # rows per chunked-prefill dispatch (the program's batch dim).
@@ -247,6 +248,9 @@ class ServeEngine:
         #   it subsumes, so fused-off arithmetic (total = prefill +
         #   decode + replay) loses exactly n_fused_dispatches when
         #   fusion is on — the identity MetricsRegistry.audit rechecks;
+        # * n_page_writes — page-writer dispatches: every program
+        #   launch is followed by one (audit checks it against
+        #   n_total_dispatches);
         # * speculation — accept rate = n_draft_accepted / n_drafted.
         self.tel = telemetry if telemetry is not None else Telemetry()
         self.uid = next_uid("e")
@@ -256,6 +260,53 @@ class ServeEngine:
         self._now = 0.0              # last sanitized step clock
         self._clock = None           # the caller's clock, this step
         self._last_decode_rows = 0   # rows in the last decode round
+        self._warm_writer(device)
+
+    # ------------------------------------------------------- page writer
+    def _write(self, out) -> None:
+        """Store a step program's ``writes`` into the pools in place
+        (``programs.write_pages`` takes them donated), one page write."""
+        c = self.cache
+        if isinstance(out, dict):
+            # a page state handed back whole instead of writes: only the
+            # benchmark's ``state_unchanged`` fault does that
+            # (tests/bench/test_bench_harness.py).  No writer runs, so
+            # no page write is counted and the audit's n_page_writes =
+            # n_total identity flags it.  The branch goes once that
+            # fault drops ``writes`` instead.
+            c.k_pages, c.v_pages = out["k_pages"], out["v_pages"]
+            return
+        c.k_pages, c.v_pages = self.programs.write_pages(
+            c.k_pages, c.v_pages, out)
+        self._c["n_page_writes"].inc()
+
+    def _warm_writer(self, device) -> None:
+        """Compile the page writer for every shape of ``writes`` this
+        engine's steps give it — decode, verify, chunk and the fused
+        pairs — by writing zeros onto the null page, placed as the step
+        programs place their outputs; no compile is left for a step."""
+        cfg = self.model.cfg
+        L, kvh, dh = cfg.n_layers, cfg.n_kv_heads, cfg.head_dim
+        kv_sh = self.programs.page_sharding(device)
+        ix_sh = self.programs.index_sharding(device)
+
+        def group(rows, width):
+            kv = jax.device_put(
+                np.zeros((L, rows, width, kvh, dh), jax.numpy.bfloat16),
+                kv_sh)
+            ix = jax.device_put(np.zeros((rows, width), np.int32), ix_sh)
+            return {"k": kv, "v": kv, "page": ix, "slot": ix}
+
+        widths = [1] + ([self.spec_k + 1] if self.spec_k else [])
+        dec = [group(self.max_batch, T) for T in widths]
+        pre = group(self.prefill_batch, self.chunk_size)
+        shapes = [(d,) for d in dec] + [(pre,)]
+        if self.fused:
+            shapes += [(d, pre) for d in dec]
+        c = self.cache
+        for writes in shapes:
+            c.k_pages, c.v_pages = self.programs.write_pages(
+                c.k_pages, c.v_pages, writes)
 
     # --------------------------------------------------------- frontend
     def check_admissible(self, req: Request) -> None:
@@ -546,9 +597,8 @@ class ServeEngine:
             args = [jax.numpy.asarray(a)
                     for a in (tokens, tables, starts, valids)]
         with phase("serve.dispatch"):
-            tok, state = self._chunk(self.params, state, *args)
-        self.cache.k_pages = state["k_pages"]
-        self.cache.v_pages = state["v_pages"]
+            tok, writes = self._chunk(self.params, state, *args)
+            self._write(writes)
         self._c["n_prefill_dispatches"].inc()
         self._c["n_prefill_chunks"].inc(len(metas))
         self._c["n_total_dispatches"].inc()
@@ -647,10 +697,9 @@ class ServeEngine:
                          "v_pages": self.cache.v_pages,
                          "page_tables": jax.numpy.asarray(tables),
                          "lengths": jax.numpy.asarray(lengths)}
-                _, state = self._decode(self.params, state,
-                                        jax.numpy.asarray(toks))
-            self.cache.k_pages = state["k_pages"]
-            self.cache.v_pages = state["v_pages"]
+                _, writes = self._decode(self.params, state,
+                                         jax.numpy.asarray(toks))
+                self._write(writes)
             self.cache.lengths[slot] += 1
             self._c["n_replay_steps"].inc()
             self._c["n_total_dispatches"].inc()
@@ -803,9 +852,8 @@ class ServeEngine:
             state = self._masked_state()
             toks = jax.numpy.asarray(tokens)
         with phase("serve.dispatch"):
-            nxt, state = program(self.params, state, toks)
-        self.cache.k_pages = state["k_pages"]
-        self.cache.v_pages = state["v_pages"]
+            nxt, writes = program(self.params, state, toks)
+            self._write(writes)
         self._c["n_total_dispatches"].inc()
         with phase("serve.wait"):
             nxt = np.asarray(nxt)
@@ -831,9 +879,9 @@ class ServeEngine:
                 jax.numpy.asarray(a)
                 for a in (tokens, p_tokens, tables, starts, valids)]
         with phase("serve.dispatch"):
-            (d_nxt, p_nxt), state = self.programs.fused(self.params, *args)
-        self.cache.k_pages = state["k_pages"]
-        self.cache.v_pages = state["v_pages"]
+            (d_nxt, p_nxt), writes = self.programs.fused(self.params,
+                                                         *args)
+            self._write(writes)
         # one launch subsumes a prefill dispatch and a decode round:
         # both sub-counters advance (their per-kind semantics — chunks
         # ingested, rounds banked — are unchanged), total only once

@@ -31,11 +31,15 @@ guarantee composes out of them — docs/serving.md):
   inside the jit'd program in f32 logits; the host only ever sees
   int32 token ids, never logits to re-reduce.
 * **The caller owns authoritative lengths/tables** — programs treat
-  ``state["lengths"]`` / ``state["page_tables"]`` as read-only inputs
-  (``decode_step_paged`` returns lengths+1 as a convenience the engine
-  overrides); host bookkeeping in serve/kv_cache.py is the source of
-  truth, which is what lets verification advance a *variable* number
-  of positions per step.
+  ``state["lengths"]`` / ``state["page_tables"]`` as read-only inputs;
+  host bookkeeping in serve/kv_cache.py is the source of truth, which
+  is what lets verification advance a *variable* number of positions
+  per step.
+* **The step programs never write the pools** — they read
+  ``state["k_pages"]`` / ``state["v_pages"]`` and return ``writes``
+  (the step's new K/V and their (page, slot) targets); the engine
+  hands those to ``ServePrograms.write_pages``, the one program that
+  takes the pools (donated) and stores into them in place.
 """
 from __future__ import annotations
 
@@ -75,9 +79,9 @@ def make_decode_step(model, sample: str = "greedy") -> Callable:
 def make_paged_decode_step(model, sample: str = "greedy",
                            tp_axis: Optional[str] = None) -> Callable:
     def paged_step(params, state, tokens):
-        logits, state = model.decode_step_paged(params, state, tokens,
-                                                tp_axis=tp_axis)
-        return _sample(logits, sample)[:, None], state
+        logits, writes = model.decode_paged(params, state, tokens,
+                                            tp_axis=tp_axis)
+        return _sample(logits, sample)[:, None], writes
     return paged_step
 
 
@@ -85,32 +89,32 @@ def make_verify_step(model, sample: str = "greedy",
                      tp_axis: Optional[str] = None) -> Callable:
     """Speculative-verification step: score T tokens per request in one
     batched pass (token 0 = last confirmed token, 1..T-1 = draft) and
-    return (greedy next-token ids (B, T), new page state).  Row b's
+    return (greedy next-token ids (B, T), writes).  Row b's
     ``nxt[b, t]`` is the target model's own prediction after consuming
     tokens 0..t — the host accepts the longest draft prefix that
     matches and takes ``nxt[b, a]`` as the free bonus token."""
     def verify_step(params, state, tokens):
-        logits, state = model.verify_step_paged(params, state, tokens,
-                                                tp_axis=tp_axis)
-        return _sample(logits, sample), state
+        logits, writes = model.verify_paged(params, state, tokens,
+                                            tp_axis=tp_axis)
+        return _sample(logits, sample), writes
     return verify_step
 
 
 def make_chunk_prefill_step(model, sample: str = "greedy",
                             tp_axis: Optional[str] = None) -> Callable:
     """Batched chunked-prefill step: ingest up to C prompt tokens each
-    for up to B_pf requests into the paged cache in ONE dispatch and
-    return (greedy next tokens (B_pf, 1), new page state).  Rows with
-    ``n_valid[b] == 0`` are inactive (null-page routed); a row's token
-    is only meaningful on the chunk that completes its prompt (it is
-    that request's first generated token); other rows' logits are
-    discarded by the engine.  Which requests share a dispatch can
-    never change a row's numerics (models/lm.prefill_chunk_paged)."""
+    for up to B_pf requests in ONE dispatch and return (greedy next
+    tokens (B_pf, 1), writes).  Rows with ``n_valid[b] == 0`` are
+    inactive (null-page routed); a row's token is only meaningful on
+    the chunk that completes its prompt (it is that request's first
+    generated token); other rows' logits are discarded by the engine.
+    Which requests share a dispatch can never change a row's numerics
+    (models/lm.prefill_chunk_paged)."""
     def chunk_step(params, state, tokens, table_rows, starts, n_valid):
-        logits, state = model.prefill_chunk_paged(
+        logits, writes = model.chunk_paged(
             params, state, tokens, table_rows, starts, n_valid,
             tp_axis=tp_axis)
-        return _sample(logits, sample)[:, None], state
+        return _sample(logits, sample)[:, None], writes
     return chunk_step
 
 
@@ -120,24 +124,25 @@ def make_fused_step(model, sample: str = "greedy",
     steady-state iteration — the decode/verify rows (``tokens``
     (B, T) against ``state``'s tables/lengths) and the chunked-prefill
     rows (``p_tokens`` (B_pf, C) with their table rows / starts /
-    valid counts).  Returns ``((d_nxt (B, T), p_nxt (B_pf, 1)), new
-    page state)``: ``d_nxt`` is exactly what the decode (T == 1) or
+    valid counts).  Returns ``((d_nxt (B, T), p_nxt (B_pf, 1)),
+    writes)``: ``d_nxt`` is exactly what the decode (T == 1) or
     verify (T > 1) program would return, ``p_nxt`` exactly what the
     chunked-prefill program would return — the scheduler applies both
     with the same host logic as the unfused paths."""
     def fused_step(params, state, tokens, p_tokens, p_table_rows,
                    p_starts, p_n_valid):
-        (d_logits, p_logits), state = model.fused_step_paged(
+        (d_logits, p_logits), writes = model.fused_paged(
             params, state, tokens, p_tokens, p_table_rows, p_starts,
             p_n_valid, tp_axis=tp_axis)
         return (_sample(d_logits, sample),
-                _sample(p_logits, sample)[:, None]), state
+                _sample(p_logits, sample)[:, None]), writes
     return fused_step
 
 
 class ServePrograms:
     """The jit-compiled serving programs (decode / chunked prefill /
-    verify) for one model, independent of any engine instance.
+    verify / fused, and the page writer) for one model, independent of
+    any engine instance.
 
     Engines historically built their own ``jax.jit`` wrappers, which
     meant N replicas of the same model paid N compiles of the *same*
@@ -146,6 +151,13 @@ class ServePrograms:
     is built once and shared: every ``ServeEngine(programs=...)``
     reuses one compile cache across replicas.  The verify program is
     built lazily so non-speculative engines never trace it.
+
+    The step programs read the page pools and never return them: each
+    returns its tokens and ``writes``, the step's new K/V with their
+    (page, slot) targets (models/lm.write_pages).  ``write_pages``
+    alone takes the pools, donated, and stores the writes in place, so
+    a step holds one copy of each pool and the step programs' own
+    arguments all stay alive.
 
     The tensor-parallel counterpart (same attribute surface, programs
     shard_map'd over a mesh) is serve/parallel.py's
@@ -158,6 +170,7 @@ class ServePrograms:
         self.model = model
         self.decode = jax.jit(make_paged_decode_step(model))
         self.chunk = jax.jit(make_chunk_prefill_step(model))
+        self.write_pages = jax.jit(model.write_pages, donate_argnums=(0, 1))
         self._verify = None
         self._fused = None
         self._params_cache: dict = {}
@@ -193,10 +206,16 @@ class ServePrograms:
         return self._params_cache[key][1]
 
     def page_sharding(self, device=None):
-        """Where the engine's page pool is allocated."""
-        if device is None:
-            return None
-        return jax.sharding.SingleDeviceSharding(device)
+        """Where the engine's page pool is allocated: committed to
+        ``device`` (the default device where None), so every step's
+        writes come back committed there too and the writer compiled
+        at the engine's build serves them."""
+        return jax.sharding.SingleDeviceSharding(
+            device if device is not None else jax.devices()[0])
+
+    def index_sharding(self, device=None):
+        """Where a step's write targets live (with its K/V, here)."""
+        return self.page_sharding(device)
 
 
 def greedy_generate(model, params, prompt_batch, n_steps: int,

@@ -72,8 +72,10 @@ RATIO_FIELDS: Dict[str, Tuple[str, str]] = {
 
 # The dispatch-accounting identity (see docs/serving.md):
 #   n_total = n_prefill + n_decode + n_replay - n_fused
+#   n_page_writes = n_total (where registered)
 _IDENTITY = ("n_total_dispatches", "n_prefill_dispatches",
-             "n_decode_steps", "n_replay_steps", "n_fused_dispatches")
+             "n_decode_steps", "n_replay_steps", "n_fused_dispatches",
+             "n_page_writes")
 
 # Crash-recovery counters (see docs/robustness.md): recovery implies
 # failure, and replay burden implies recovered requests — audit()
@@ -216,8 +218,10 @@ class MetricsRegistry:
 
     def audit(self) -> List[str]:
         """Check ``n_total = n_prefill + n_decode + n_replay -
-        n_fused`` for every label set that registered the identity
-        counters, plus fleet-wide over the summed totals.  Returns a
+        n_fused`` (and, where registered, ``n_page_writes = n_total``:
+        every program launch is followed by one page write) for every
+        label set that registered the identity counters, plus
+        fleet-wide over the summed totals.  Returns a
         list of violation strings (empty = healthy)."""
         groups: Dict[Tuple[Tuple[str, str], ...],
                      Dict[str, float]] = {}
@@ -254,6 +258,12 @@ class MetricsRegistry:
             if vals["n_total_dispatches"] != want:
                 errs.append(f"{dict(labels)}: n_total_dispatches="
                             f"{vals['n_total_dispatches']} != {want}")
+            if vals.get("n_page_writes", vals["n_total_dispatches"]) \
+                    != vals["n_total_dispatches"]:
+                errs.append(f"{dict(labels)}: n_page_writes="
+                            f"{vals['n_page_writes']} != "
+                            f"n_total_dispatches="
+                            f"{vals['n_total_dispatches']}")
         want = (fleet["n_prefill_dispatches"] + fleet["n_decode_steps"]
                 + fleet["n_replay_steps"] - fleet["n_fused_dispatches"])
         if groups and fleet["n_total_dispatches"] != want:

@@ -79,6 +79,52 @@ def test_verify_step_bitwise_matches_sequential_decode(qwen3):
         np.asarray(st2["v_pages"], np.float32))
 
 
+def test_verify_window_crossing_the_table_end_matches_decode(qwen3):
+    """Row 0's T-token window runs past the end of its gathered table
+    (lengths + T > n * ps): the positions it can confirm give the logits
+    sequential decode steps give, and the pages end up the same — the
+    overlay drops the lanes past the buffer and the writer routes them
+    to the null page; neither clamps them onto the row's last page."""
+    cfg, model, params = qwen3
+    B, ps, n, T = 2, 8, 3, 5
+    specs = model.paged_state_specs(B, n_pages=16, page_size=ps,
+                                    max_pages_per_seq=n)
+    rng = np.random.default_rng(1)
+    shape = specs["k_pages"].shape
+    k_pages = jnp.asarray(rng.normal(size=shape), jnp.bfloat16)
+    v_pages = jnp.asarray(rng.normal(size=shape), jnp.bfloat16)
+    tables = jnp.asarray([[3, 7, 11], [2, 5, 9]], jnp.int32)
+    lengths = np.asarray([n * ps - 3, 6], np.int32)   # row 0: 21 + 5 > 24
+    toks = rng.integers(0, cfg.vocab_size, size=(B, T)).astype(np.int32)
+
+    st_ = {"k_pages": k_pages, "v_pages": v_pages, "page_tables": tables,
+           "lengths": jnp.asarray(lengths)}
+    seq = []
+    decode = jax.jit(model.decode_step_paged)
+    for t in range(T):
+        lg, st_ = decode(params, st_, jnp.asarray(toks[:, t:t + 1]))
+        seq.append(np.asarray(lg))
+    seq = np.stack(seq, axis=1)                    # (B, T, V)
+
+    st2 = {"k_pages": k_pages, "v_pages": v_pages, "page_tables": tables,
+           "lengths": jnp.asarray(lengths)}
+    ver, st2 = jax.jit(model.verify_step_paged)(params, st2,
+                                                jnp.asarray(toks))
+    ver = np.asarray(ver)
+    for b in range(B):
+        ok = min(T, n * ps - lengths[b])           # confirmable positions
+        np.testing.assert_array_equal(seq[b, :ok], ver[b, :ok])
+    assert min(T, n * ps - lengths[0]) < T         # the window crosses
+    for name in ("k_pages", "v_pages"):
+        np.testing.assert_array_equal(np.asarray(st_[name], np.float32),
+                                      np.asarray(st2[name], np.float32))
+    # row 0's last page keeps its history: nothing clamped onto it
+    last = np.asarray(k_pages[:, 11], np.float32)
+    np.testing.assert_array_equal(
+        np.asarray(st2["k_pages"][:, 11], np.float32)[:, :lengths[0] % ps],
+        last[:, :lengths[0] % ps])
+
+
 # ------------------------------------------------------- engine parity
 def test_spec_engine_token_exact_vs_greedy_generate(qwen3):
     """Speculation on, more requests than slots, ragged prompts: every
