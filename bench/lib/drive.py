@@ -25,7 +25,7 @@ from typing import Callable, Dict, List, Optional
 import numpy as np
 
 from .traffic import ReqSpec
-from .work import Work, decode_rows, prefill_rows
+from .work import Work
 
 #: the step programs of a bundle, by their public names
 STEP_PROGRAMS = ("decode", "chunk", "fused", "verify")
@@ -80,14 +80,16 @@ class Recorder:
     """Notes, per dispatch of a step program, its host time and the
     inputs that say what work it asks for (traced runs only).  The
     inputs are kept as they came and read back after the window, so the
-    record adds no device-to-host copy to the measured path.
+    record adds no device-to-host copy to the measured path; each
+    dispatch's work is then counted by ``arch.dispatch_work``.
 
     A preempted request is replayed one single-row decode dispatch per
     token it had; the backend's ``n_replay_steps`` counter, read after
     each step (``end_step``), says how many of a step's dispatches were
     replays."""
 
-    def __init__(self, dims, clock: Callable[[], float]):
+    def __init__(self, arch, dims, clock: Callable[[], float]):
+        self.arch = arch
         self.dims = dims
         self.clock = clock
         self.reset(0)
@@ -127,16 +129,18 @@ class Recorder:
         """Every dispatch since ``reset``, with its work."""
         out = []
         for kind, t0, t1, keep in self._raw:
+            decode = prefill = None
+            rows = 0
             if kind == "chunk":
-                work, rows = prefill_rows(self.dims, np.asarray(keep[0]),
-                                          np.asarray(keep[1])), 0
+                prefill = (np.asarray(keep[0]), np.asarray(keep[1]))
             else:
                 lengths = np.asarray(keep[0])
                 live = lengths[lengths > 0]
-                work, rows = decode_rows(self.dims, live, T=keep[1]), len(live)
+                decode, rows = (live, keep[1]), len(live)
                 if kind == "fused":
-                    work = work + prefill_rows(self.dims, np.asarray(keep[2]),
-                                               np.asarray(keep[3]))
+                    prefill = (np.asarray(keep[2]), np.asarray(keep[3]))
+            work = self.arch.dispatch_work(self.dims, decode=decode,
+                                           prefill=prefill)
             out.append(Dispatch(kind, t0, t1, work, rows))
         for first, end, n in self._replays:
             for d in out[first:end]:

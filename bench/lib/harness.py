@@ -106,7 +106,6 @@ class Run:
     dispatches: Optional[List] = None        # traced runs: every dispatch
     traced_host: Optional[tuple] = None      # (t0, t1) on the client clock
     trace: Any = None                        # trace.Reduced
-    weight_bytes: int = 0
 
     @property
     def window_recs(self):
@@ -208,9 +207,10 @@ def _trace_hook(seconds: float, traced: bool, tmp: str, runner):
 
 
 class Runner:
-    """Set-up of one cell in this process: the model, its program
-    bundle (compiled once, shared by every backend built here), and the
-    compile counter.  ``build`` makes the weights for a seed and the
+    """Set-up of one cell in this process: the model of the cell's
+    architecture module (``cell.arch``), its program bundle (compiled
+    once, shared by every backend built here), and the compile
+    counter.  ``build`` makes the weights for a seed and the
     backend over them, and warms it up."""
 
     def __init__(self, cell, *, traced: bool = False,
@@ -219,20 +219,20 @@ class Runner:
         from repro.launch.compile_cache import use_compile_cache
         from repro.models import build_model
         from repro.serve.step import ServePrograms
-        from .weights import dims as dims_of, program_config
         log(f"compile cache {use_compile_cache()}")
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
         self.compiles = Compiles()
         self.cell = cell
         self.traced = traced
-        self.k = dims_of(cell.config)
-        self.model = build_model(program_config(cell.config))
+        self.arch = cell.arch
+        self.k = self.arch.dims(cell.config)
+        self.model = build_model(self.arch.program_config(cell.config))
         self._t0 = 0.0
         self._paused = 0.0
         wraps = [fault] if fault is not None else []
         self.recorder = None
         if traced:
-            self.recorder = Recorder(self.k, self.clock)
+            self.recorder = Recorder(self.arch, self.k, self.clock)
             wraps.append(self.recorder.wrap)
         self.programs = Wrapped(ServePrograms(self.model), wraps)
         self.backend = None
@@ -259,7 +259,8 @@ class Runner:
         import jax
         from .weights import check_layout, make_weights
         self.free()
-        self.weights = make_weights(self.k, seed, self.cell.config["dtype"])
+        self.weights = make_weights(self.arch.shapes(self.k), self.arch.std,
+                                    seed, self.cell.config["dtype"])
         check_layout(self.weights, self.model.abstract())
         tp = self.cell.traffic
         opts = _serve_options(self.cell, tokens_bound(tp))
@@ -334,7 +335,8 @@ class Runner:
         t = time.perf_counter()
         picked = oracle.sample(recs, seed, tokens=CHECK_TOKENS,
                                max_requests=CHECK_MAX_REQUESTS)
-        cmp = oracle.compare(self.weights, self.k, picked, control=control)
+        cmp = oracle.compare(self.arch, self.weights, self.k, picked,
+                             control=control)
         log(f"reference over {len(picked)} requests, {cmp['tokens']} served "
             f"tokens, in {time.perf_counter() - t:.3f} s")
         limit = float(self.cell.cell["check"]["max_logit_gap"])
@@ -398,7 +400,6 @@ def main(argv=None, *, t_start: Optional[float] = None,
     if traced and peaks is None:
         peaks = peaks_for(dev0.device_kind)
 
-    from . import work
     runner = Runner(cell, traced=traced, fault=fault)
     n_warm = runner.build(args.seed)
     tmp = tempfile.mkdtemp(prefix="bench_trace_")
@@ -425,9 +426,7 @@ def main(argv=None, *, t_start: Optional[float] = None,
 
     run = Run(cell=cell, seconds=args.seconds, setup_s=setup_s, recs=recs,
               tokens_in_window=out["tokens_in_window"], chips=cell.chips,
-              end=out["end"],
-              dims=runner.k, peak=peaks,
-              weight_bytes=work.weight_bytes(runner.k))
+              end=out["end"], dims=runner.k, peak=peaks)
     device: Dict[str, Any] = {"platform": dev0.platform,
                               "kind": dev0.device_kind,
                               "count": len(devices),

@@ -1,18 +1,18 @@
 """Decide ``correct``: what the timed path served, against the reference.
 
 After the window a sample of the finished requests, drawn from the
-seed, is run through the plain float32 reference (``reference.py``)
-once each, over its prompt and the tokens it was served.  For every
-served token the reference gives the gap by which that token's logit
-lies below its own best; the number compared is the widest gap.
-Greedy decoding in bfloat16 picks the reference's best token or one
-within rounding of it; a step that computes something else picks
-tokens far down.
+seed, is run through the plain float32 reference (``logits`` of the
+cell's architecture module, ``bench/arch/``) once each, over its prompt
+and the tokens it was served.  For every served token the reference
+gives the gap by which that token's logit lies below its own best; the
+number compared is the widest gap.  Greedy decoding in bfloat16 picks
+the reference's best token or one within rounding of it; a step that
+computes something else picks tokens far down.
 
 The control puts the reference in the program's place at the next
-precision down (int8, see ``reference.py``): at each position of the
-same prompts and tokens it reads the gap of the token the int8
-reference puts first.
+precision down (``logits(..., quant=)``, int8 or fp8): at each position
+of the same prompts and tokens it reads the gap of the token the
+lower-precision reference puts first.
 
 Separately, every request that finished must have been served exactly
 its ``max_new`` tokens, all in the vocabulary, the stream of events
@@ -25,8 +25,6 @@ from typing import Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-from . import reference
 
 
 @jax.jit
@@ -83,11 +81,11 @@ def _pad(a, n):
     return out
 
 
-def compare(weights, dims, picked, *, control: Optional[str] = None
+def compare(arch, weights, dims, picked, *, control: Optional[str] = None
             ) -> Dict[str, float]:
     """Widest gap of the served tokens (and, with ``control``, of the
-    tokens the control's reference puts first) below the reference's
-    best logit, over every token of ``picked``."""
+    tokens the control's reference puts first) below the best logit of
+    ``arch``'s reference, over every token of ``picked``."""
     out = {"served": 0.0, "tokens": 0}
     if control:
         out["control"] = 0.0
@@ -96,12 +94,12 @@ def compare(weights, dims, picked, *, control: Optional[str] = None
         P, n = len(r.spec.prompt), len(served)
         seq = np.concatenate([r.spec.prompt, served[:-1]]).astype(np.int32)
         rows = np.arange(P - 1, P - 1 + n)
-        ref = reference.logits(weights, dims, seq, rows)
+        ref = arch.logits(weights, dims, seq, rows)
         g = np.asarray(_gaps(ref, jnp.asarray(_pad(served, ref.shape[0]))))
         out["served"] = max(out["served"], float(g[:n].max()))
         out["tokens"] += n
         if control:
-            low = reference.logits(weights, dims, seq, rows, quant=control)
+            low = arch.logits(weights, dims, seq, rows, quant=control)
             c = _first(low)
             gc = np.asarray(_gaps(ref, c))
             out["control"] = max(out["control"], float(gc[:n].max()))

@@ -1,6 +1,7 @@
 """A small checkout for the benchmark's CPU tests: the repo's ``bench/``
 tree beside a ``BENCHMARK.json`` whose cells are smoke-sized, so the
-harness runs end to end on the CPU in seconds."""
+harness runs end to end on the CPU in seconds.  A test may place an
+architecture module of its own in the checkout's ``bench/arch/``."""
 from __future__ import annotations
 
 import copy
@@ -16,7 +17,8 @@ if str(REPO) not in sys.path:
 #: qwen3-shaped, 2 layers of width 64 (qk-norm, tied table, GQA 4/2)
 SMOKE_CONFIG = {
     "name": "smoke-dense", "source": "tests/bench/benchkit.py",
-    "dtype": "bfloat16", "arch": {"qk_norm": True, "attention_bias": False},
+    "dtype": "bfloat16", "reference": "bench/arch/dense_gqa.py",
+    "arch": {"qk_norm": True, "attention_bias": False},
     "program": {"attn_kv_chunk": 16},
     "model": {"hidden_size": 64, "intermediate_size": 128,
               "num_attention_heads": 4, "num_key_value_heads": 2,
@@ -67,13 +69,17 @@ def smoke_sizes(monkeypatch) -> None:
 CPU_PEAKS = {"bf16_flops": 1e12, "hbm_bytes_s": 1e11}
 
 
-def make_root(tmp: pathlib.Path, *, cells=None,
-              config=SMOKE_CONFIG) -> pathlib.Path:
-    """``tmp`` as a checkout: ``bench/`` copied, plus one smoke config,
-    the smoke traffic mix and a cell per entry of ``cells`` (name ->
-    cell dict), listed in a fresh ``BENCHMARK.json``."""
+def make_root(tmp: pathlib.Path, *, cells=None, config=SMOKE_CONFIG,
+              arch=None) -> pathlib.Path:
+    """``tmp`` as a checkout: ``bench/`` copied, plus the smoke config
+    (named ``smoke-dense``), the smoke traffic mix and a cell per entry
+    of ``cells`` (name -> cell dict), listed in a fresh
+    ``BENCHMARK.json``; ``arch``, a module file, is copied to
+    ``bench/arch/`` under its own name."""
     shutil.copytree(REPO / "bench", tmp / "bench",
                     ignore=shutil.ignore_patterns("__pycache__"))
+    if arch is not None:
+        shutil.copy(arch, tmp / "bench/arch" / pathlib.Path(arch).name)
     bench = json.loads((REPO / "BENCHMARK.json").read_text())
     cells = cells or {"smoke.chat": SMOKE_CELL}
     (tmp / "bench/configs/smoke-dense.json").write_text(

@@ -213,11 +213,12 @@ def test_benchmark_file_keeps_the_contract():
 
 
 def test_weights_follow_the_seed():
-    from bench.lib.weights import dims, make_weights
-    k = dims(benchkit.SMOKE_CONFIG)
-    a = make_weights(k, 2**40 + 1, "bfloat16")
-    b = make_weights(k, 2**40 + 1, "bfloat16")
-    c = make_weights(k, 2**40 + 2, "bfloat16")
+    from bench.lib.weights import make_weights
+    arch = spec.load_arch(REPO / benchkit.SMOKE_CONFIG["reference"])
+    tree = arch.shapes(arch.dims(benchkit.SMOKE_CONFIG))
+    a = make_weights(tree, arch.std, 2**40 + 1, "bfloat16")
+    b = make_weights(tree, arch.std, 2**40 + 1, "bfloat16")
+    c = make_weights(tree, arch.std, 2**40 + 2, "bfloat16")
     wq = np.asarray(a["layers"]["mix"]["wq"], np.float32)
     assert (wq == np.asarray(b["layers"]["mix"]["wq"], np.float32)).all()
     assert not (wq == np.asarray(c["layers"]["mix"]["wq"], np.float32)).all()
